@@ -185,6 +185,8 @@ def main() -> None:
                          "backends and reports the process-fleet speedup; "
                          "rows land in BENCH_gateway_wall.json)")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     mode = "smoke" if args.smoke else "fast" if args.fast else "full"
     _register(mode, backend=args.backend, clock=args.clock)
     names = args.only or list(BENCHES)

@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, list_configs
 from repro.core.runtime.accounting import MemoryAccountant
 from repro.models import build_model
@@ -22,6 +23,7 @@ def main():
     ap.add_argument("--arch", default="qwen3-8b", choices=list_configs())
     ap.add_argument("--steps", type=int, default=5)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     print(f"[quickstart] {cfg.name}: {cfg.param_count()/1e9:.1f}B params "

@@ -16,6 +16,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.predictor import MaestroPred, PredictorConfig
 from repro.core.predictor.gbdt import GBDTConfig
 from repro.data.tracegen import generate_trace, stratified_temporal_split
@@ -46,6 +47,7 @@ def main(n_jobs: int = 6, train_jobs: int = 300, policy: str = "maestro",
     so the fleet genuinely runs concurrently, "socket" runs the same
     workers over the framed-TCP transport (localhost here; the remote-host
     path is ``python -m repro.serving.worker --listen``)."""
+    enable_compile_cache()
     print(f"[serve] training the agent-aware cost predictor "
           f"({train_jobs} recorded jobs) ...")
     pred = train_predictor(train_jobs)

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint.checkpoint import latest_step, restore, save
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.distributed.fault import StragglerDetector
 from repro.models import build_model
@@ -39,6 +40,7 @@ def main():
     ap.add_argument("--ckpt", default="/tmp/repro_train_ckpt")
     ap.add_argument("--n-micro", type=int, default=2)
     args = ap.parse_args()
+    enable_compile_cache()
 
     # ~100M-class config: qwen3 family, scaled down
     cfg = dataclasses.replace(

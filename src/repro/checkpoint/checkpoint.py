@@ -34,9 +34,7 @@ def save(path: str, tree, step: int, extra: Optional[Dict] = None,
     arrays, treedef = _flatten(tree)
     manifest = {
         "step": step,
-        "treedef": jax.tree_util.tree_structure(tree).serialize_using_proto().hex()
-        if hasattr(jax.tree_util.tree_structure(tree), "serialize_using_proto")
-        else None,
+        "treedef": treedef.serialize_using_proto().hex(),
         "n_leaves": len(arrays),
         "shapes": {k: list(v.shape) for k, v in arrays.items()},
         "dtypes": {k: str(v.dtype) for k, v in arrays.items()},

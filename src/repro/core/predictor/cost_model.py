@@ -34,6 +34,35 @@ class HardwareSpec:
 A100_40G = HardwareSpec(name="a100-40g", peak_flops=312e12, hbm_bw=1555e9,
                         hbm_capacity=40e9, host_link_bw=25e9)
 
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+DEVICE_SPECS: Dict[str, HardwareSpec] = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB of HBM
+    # at 819 GB/s per chip
+    "TPU v5 lite": HardwareSpec(name="tpu-v5e", peak_flops=197e12,
+                                hbm_bw=819e9, hbm_capacity=16e9),
+}
+
+
+class UnknownDeviceError(KeyError):
+    """An accelerator whose ``device_kind`` has no row in ``DEVICE_SPECS``:
+    its peaks are unknown, and assuming another chip's would be wrong."""
+
+
+def hardware_spec(device) -> HardwareSpec:
+    """The cost model's spec for a ``jax.Device``: its ``DEVICE_SPECS`` row
+    on an accelerator; the default spec on the CPU, where runs use the
+    virtual clock and no device rate is measured. An accelerator kind not
+    in the table raises :class:`UnknownDeviceError`."""
+    if device.platform == "cpu":
+        return HardwareSpec()
+    try:
+        return DEVICE_SPECS[device.device_kind]
+    except KeyError:
+        raise UnknownDeviceError(
+            f"no HardwareSpec for {device.platform} device kind "
+            f"{device.device_kind!r}; add its published peaks to "
+            f"DEVICE_SPECS") from None
+
 
 @dataclasses.dataclass(frozen=True)
 class ModelProfile:

@@ -13,8 +13,13 @@ Grid (batch, page_slots); the page-slot dimension is innermost/sequential so
 online-softmax state persists in VMEM scratch, exactly like
 ``paged_attention``. The block table and per-sequence visible-KV lengths are
 scalar-prefetched and drive the K/V page BlockSpec index maps. GQA: q
-[B, C, H, hd] is regrouped to [Hkv, C*g, hd] inside the kernel; K/V pages
-keep their native [page, Hkv, hd] layout (never repeated).
+[B, C, H, hd] is regrouped outside the kernel to [B, Hkv, C*g, hd] (query
+rows r = c*g + sub grouped by KV head), with each row's position alongside
+as a [B, 1, C*g] lane vector; K/V pages keep their native [page, Hkv, hd]
+layout (never repeated). Inside, scores are [Hkv, page, C*g]: keys on
+sublanes and query rows on lanes, since a page (16 tokens) is far narrower
+than a 128-lane tile. The output comes back as [B, Hkv, hd, C*g] and is
+regrouped to [B, C, H, hd] outside.
 """
 from __future__ import annotations
 
@@ -28,7 +33,7 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _chunk_kernel(block_table, k_lens, q_ref, pos_ref, k_ref, v_ref, o_ref,
+def _chunk_kernel(block_table, k_lens, q_ref, qpos_ref, k_ref, v_ref, o_ref,
                   m_scr, l_scr, acc_scr, *, page_size: int, n_slots: int,
                   scale: float):
     b = pl.program_id(0)
@@ -44,41 +49,32 @@ def _chunk_kernel(block_table, k_lens, q_ref, pos_ref, k_ref, v_ref, o_ref,
 
     @pl.when(s < n_used)
     def _compute():
-        q = q_ref[0]                                   # [C, H, hd]
+        q = q_ref[0]                                   # [Hkv, R, hd]
         k = k_ref[0]                                   # [page, Hkv, hd]
         v = v_ref[0]
-        C, H, hd = q.shape
-        Hkv = k.shape[1]
-        g = H // Hkv
-        # head h = kvh*g + sub (jnp.repeat order) -> rows grouped by kv head
-        qg = (q.reshape(C, Hkv, g, hd).transpose(1, 0, 2, 3)
-              .reshape(Hkv, C * g, hd).astype(jnp.float32))
-        kf = k.astype(jnp.float32)
-        # scores [Hkv, C*g, page]
+        # scores with keys on sublanes and query rows on lanes:
+        # [Hkv, page, R] (a page is far narrower than a lane tile)
         sc = jax.lax.dot_general(
-            qg, kf, (((2,), (2,)), ((0,), (1,))),
+            k, q, (((2,), (2,)), ((1,), (0,))),
             preferred_element_type=jnp.float32) * scale
-        qpos = jnp.repeat(pos_ref[0], g)               # [C*g]
         kpos = s * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, sc.shape, 2)
-        sc = jnp.where(qpos[None, :, None] >= kpos, sc, NEG_INF)
-        m_prev = m_scr[...]                            # [Hkv, C*g, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=2, keepdims=True))
+            jnp.int32, sc.shape, 1)
+        sc = jnp.where(qpos_ref[0] >= kpos, sc, NEG_INF)
+        m_prev = m_scr[...]                            # [Hkv, 1, R]
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
         p = jnp.exp(sc - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=2, keepdims=True)
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
         m_scr[...] = m_new
-        pv = jax.lax.dot_general(
-            p, v.astype(jnp.float32), (((2,), (0,)), ((0,), (1,))))
-        acc_scr[...] = acc_scr[...] * alpha + pv       # [Hkv, C*g, hd]
+        pv = jax.lax.dot_general(                      # [Hkv, hd, R]
+            v.astype(jnp.float32), p, (((0,), (1,)), ((1,), (0,))),
+            preferred_element_type=jnp.float32)
+        acc_scr[...] = acc_scr[...] * alpha + pv
 
     @pl.when(s == n_slots - 1)
     def _finalize():
-        acc = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
-        C, H, hd = o_ref.shape[1], o_ref.shape[2], o_ref.shape[3]
-        Hkv = acc.shape[0]
-        o_ref[0] = (acc.reshape(Hkv, C, H // Hkv, hd).transpose(1, 0, 2, 3)
-                    .reshape(C, H, hd).astype(o_ref.dtype))
+        o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
+                    ).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -97,8 +93,15 @@ def chunk_prefill_attention(q: jax.Array, k_pages: jax.Array,
     """
     B, C, H, hd = q.shape
     Hkv = k_pages.shape[2]
+    g = H // Hkv
+    R = C * g
     n_slots = block_table.shape[1]
     k_lens = jnp.max(positions, axis=1) + 1
+    # head h = kvh*g + sub (jnp.repeat order): query rows r = c*g + sub are
+    # grouped by kv head, and each row carries its token's position
+    qg = (q.reshape(B, C, Hkv, g, hd).transpose(0, 2, 1, 3, 4)
+          .reshape(B, Hkv, R, hd))
+    qpos = jnp.repeat(positions, g, axis=1)[:, None, :]       # [B, 1, R]
     grid = (B, n_slots)
     kernel = functools.partial(_chunk_kernel, page_size=page_size,
                                n_slots=n_slots, scale=hd ** -0.5)
@@ -106,23 +109,25 @@ def chunk_prefill_attention(q: jax.Array, k_pages: jax.Array,
         num_scalar_prefetch=2,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, C, H, hd), lambda b, s, bt, kl: (b, 0, 0, 0)),
-            pl.BlockSpec((1, C), lambda b, s, bt, kl: (b, 0)),
+            pl.BlockSpec((1, Hkv, R, hd), lambda b, s, bt, kl: (b, 0, 0, 0)),
+            pl.BlockSpec((1, 1, R), lambda b, s, bt, kl: (b, 0, 0)),
             pl.BlockSpec((1, page_size, Hkv, hd),
                          lambda b, s, bt, kl: (bt[b, s], 0, 0, 0)),
             pl.BlockSpec((1, page_size, Hkv, hd),
                          lambda b, s, bt, kl: (bt[b, s], 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, C, H, hd),
+        out_specs=pl.BlockSpec((1, Hkv, hd, R),
                                lambda b, s, bt, kl: (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((Hkv, C * (H // Hkv), 1), jnp.float32),
-            pltpu.VMEM((Hkv, C * (H // Hkv), 1), jnp.float32),
-            pltpu.VMEM((Hkv, C * (H // Hkv), hd), jnp.float32),
+            pltpu.VMEM((Hkv, 1, R), jnp.float32),
+            pltpu.VMEM((Hkv, 1, R), jnp.float32),
+            pltpu.VMEM((Hkv, hd, R), jnp.float32),
         ],
     )
     fn = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, C, H, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, hd, R), q.dtype),
         interpret=interpret)
-    return fn(block_table, k_lens, q, positions, k_pages, v_pages)
+    out = fn(block_table, k_lens, qg, qpos, k_pages, v_pages)
+    return (out.reshape(B, Hkv, hd, C, g).transpose(0, 3, 1, 4, 2)
+            .reshape(B, C, H, hd))

@@ -62,6 +62,15 @@ class ClusterSpec:
         return int(max(n.cluster_id for n in self.nodes)) + 1
 
 
+def host_params(model, seed: int) -> Any:
+    """A model's host-tier parameter tree (numpy), drawn from ``seed`` on the
+    host's CPU device: the host tier is host memory by design, so nothing is
+    drawn on an accelerator (no f32 draw of a full-width leaf there, and no
+    device -> host -> device round trip before the first activation)."""
+    with jax.default_device(jax.devices("cpu")[0]):
+        return jax.tree.map(np.asarray, model.init(jax.random.PRNGKey(seed)))
+
+
 def build_zoo(model_names: Sequence[str] = DEFAULT_ZOO, seed: int = 1
               ) -> Tuple[Dict[str, Any], Dict[str, Any]]:
     """Tiny real models (reduced configs) + host-tier numpy parameter trees.
@@ -72,8 +81,7 @@ def build_zoo(model_names: Sequence[str] = DEFAULT_ZOO, seed: int = 1
         cfg = get_config(name).reduced()
         m = build_model(cfg)
         zoo[name] = m
-        host[name] = jax.tree.map(np.asarray,
-                                  m.init(jax.random.PRNGKey(seed + i)))
+        host[name] = host_params(m, seed + i)
     return zoo, host
 
 
@@ -109,7 +117,8 @@ def build_fleet(spec: Optional[ClusterSpec] = None,
     """Instantiate the fleet; node ids are positional.
 
     ``backend="inproc"`` (default) returns in-process ``NodeRuntime``
-    objects; ``backend="process"`` spawns one worker process per node and
+    objects, node ``i`` on local device ``i`` modulo the device count;
+    ``backend="process"`` spawns one worker process per node and
     returns ``NodeHandle`` proxies (each child builds its own zoo from the
     same ``model_names`` + ``seed``, so the fleets are numerically
     identical — ``zoo``/``host`` are ignored there); ``backend="socket"``
@@ -138,9 +147,13 @@ def build_fleet(spec: Optional[ClusterSpec] = None,
                          "(expected 'inproc', 'process' or 'socket')")
     if zoo is None or host is None:
         zoo, host = build_zoo(spec.model_names, seed=seed)
+    # one node per device, round robin: on a multi-chip host each replica
+    # holds its own weights and KV on its own chip
+    devices = jax.local_devices()
     fleet = []
     for nid, ns in enumerate(spec.nodes):
         fleet.append(NodeRuntime(nid, ns.cluster_id, zoo, host,
+                                 device=devices[nid % len(devices)],
                                  hbm_budget=ns.hbm_budget,
                                  max_slots=ns.max_slots, s_max=ns.s_max,
                                  prefix_cache=ns.prefix_cache,

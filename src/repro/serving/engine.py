@@ -93,6 +93,11 @@ class Request:
     prefill_avoided: int = 0              # prompt tokens served from cache
     submit_s: float = 0.0                 # wall stamp at engine submit
     ttft_s: float = 0.0                   # wall submit -> first kept token
+    # None keeps no logits; a list collects, for each token the host picks
+    # (prefill, chunk or one-token decode, not the on-device horizon), that
+    # token's f32 logits row [Vp], left on the device — for correctness
+    # checks against a reference
+    logits: Optional[List[Any]] = None
 
 
 class Engine:
@@ -218,7 +223,8 @@ class Engine:
             # first-token argmax folded into the jitted prefill: the host
             # fetches one int32 per sequence, never a logits row
             logits, cache = model.prefill(p, toks, extras)
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
+            return (jnp.argmax(logits, axis=-1).astype(jnp.int32), logits,
+                    cache)
 
         self._prefill_fwd = _model_jit(model, ("prefill_tok",),
                                        lambda: jax.jit(_prefill_tok))
@@ -237,7 +243,7 @@ class Engine:
                     p, kp, vp, toks, pos, bt, rows, offs, last_idx,
                     attend=attend_c)
                 return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
-                        kp, vp)
+                        logits, kp, vp)
 
             self._chunk_fwd = _model_jit(
                 model, ("chunk_tok", kv_backend, self.page_tokens),
@@ -389,6 +395,11 @@ class Engine:
             self._prefill_shapes.add(sig)
             self.prefill_compiles += 1
 
+    @staticmethod
+    def _keep_logits(req: Request, logits, row: int) -> None:
+        if req.logits is not None:
+            req.logits.append(logits[row])
+
     def _first_token(self, req: Request, tok: int) -> None:
         req.out.append(tok)
         if not req.ttft_s and req.submit_s:
@@ -437,9 +448,9 @@ class Engine:
 
     def _prefill_full(self, req: Request, slot: int) -> None:
         toks = jnp.asarray(req.tokens, jnp.int32)[None, :]
-        first_tok, cache = self._prefill_fwd(self.params, toks,
-                                             req.extras
-                                             or self._modal_extras or {})
+        first_tok, logits, cache = self._prefill_fwd(
+            self.params, toks, req.extras or self._modal_extras or {})
+        self._keep_logits(req, logits, 0)
         P = len(req.tokens)
         self._note_prefill_shape(("full", P))
         self.stat_prefill_tokens += P
@@ -495,13 +506,14 @@ class Engine:
 
         def _suffix_tok(p, toks, pk, pv):
             logits, k_sfx, v_sfx = model.prefill_suffix(p, toks, pk, pv)
-            return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
+            return (jnp.argmax(logits, axis=-1).astype(jnp.int32), logits,
                     k_sfx, v_sfx)
 
-        first_tok, k_sfx, v_sfx = _model_jit(
+        first_tok, logits, k_sfx, v_sfx = _model_jit(
             self.model, ("prefill_suffix_tok",),
             lambda: jax.jit(_suffix_tok))(
             self.params, toks, pk, pv)
+        self._keep_logits(req, logits, 0)
         self.binding.write_prompt_at(req.req_id, k_sfx[:, 0], v_sfx[:, 0], M)
         self.positions[slot] = len(req.tokens)
         self._tables_dirty = True
@@ -562,7 +574,7 @@ class Engine:
         self._note_prefill_shape(("chunk", C))
         self._tables_dirty = True
         plane = self.binding.plane
-        tok_dev, plane.k, plane.v = self._chunk_fwd(
+        tok_dev, logits, plane.k, plane.v = self._chunk_fwd(
             self.params, plane.k, plane.v, jnp.asarray(toks),
             jnp.asarray(pos), jnp.asarray(bt), jnp.asarray(rows),
             jnp.asarray(offs), jnp.asarray(last_idx))
@@ -574,6 +586,7 @@ class Engine:
             del self._prefill_pos[rid]
             slot = self.slot_of[rid]
             self.positions[slot] = len(req.tokens)
+            self._keep_logits(req, logits, slot)
             self._first_token(req, int(nxt[slot]))
             if self._pc is not None:
                 hit = self._hits.pop(rid, None)
@@ -659,6 +672,7 @@ class Engine:
                 req = self.active[rid]
                 slot = self.slot_of[rid]
                 tok = int(nxt[slot])
+                self._keep_logits(req, logits, slot)
                 req.out.append(tok)
                 self.positions[slot] += 1
                 if (len(req.out) >= req.max_new
@@ -831,6 +845,8 @@ class Engine:
         self.positions[slot] = 0
         self._tables_dirty = True
         req.out.clear()
+        if req.logits is not None:
+            req.logits.clear()
         req.ttft_s = 0.0            # the discarded first token doesn't count
         return req
 

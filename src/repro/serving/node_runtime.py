@@ -1,5 +1,9 @@
 """Node-level multi-model runtime: real model colocation on one device.
 
+Each node is given its device: its weights, KV arena planes, engine state
+and step inputs all live there, so replicas on a multi-chip host each hold
+their own copy on their own chip.
+
 Holds a zoo of (small) models; weights move between DEVICE (jnp arrays) and
 HOST (numpy) following the hierarchical residency manager — a Sleeping model
 keeps its compiled executable cache (the CUDA-graph analogue: jax.jit cache
@@ -15,7 +19,7 @@ from typing import Any, Dict, Optional
 import jax
 import numpy as np
 
-from repro.core.predictor.cost_model import HardwareSpec, ModelProfile
+from repro.core.predictor.cost_model import ModelProfile, hardware_spec
 from repro.core.runtime.accounting import MemoryAccountant
 from repro.core.runtime.coordination import (EngineInfo, EngineState,
                                              plan_degradation)
@@ -39,8 +43,11 @@ class NodeRuntime:
                  prefix_cache_pages: int = 256,
                  max_batch_tokens: Optional[int] = None,
                  prefill_chunk_tokens: int = 0,
-                 decode_horizon: int = 1):
+                 decode_horizon: int = 1,
+                 device: Optional[jax.Device] = None):
         self.node_id = node_id
+        # the node's device (default: the first local one)
+        self.device = device if device is not None else jax.local_devices()[0]
         self.cluster_id = cluster_id
         self.zoo = zoo
         self.host_params = host_params      # numpy trees (host tier)
@@ -62,6 +69,7 @@ class NodeRuntime:
         self.max_batch_tokens = max_batch_tokens
         self.prefill_chunk_tokens = prefill_chunk_tokens
         self.decode_horizon = decode_horizon
+        hw = hardware_spec(self.device)
         profiles = {
             name: ModelProfile(
                 name=name, weight_bytes=_tree_bytes(host_params[name]),
@@ -73,7 +81,7 @@ class NodeRuntime:
                 state_bytes=m.cfg.ssm_state_bytes(),
                 prefill_flops_per_token=2.0 * m.cfg.active_param_count(),
                 decode_bytes_per_token=2.0 * m.cfg.active_param_count(),
-                hw=HardwareSpec())
+                hw=hw)
             for name, m in zoo.items()}
         self.profiles = profiles
         self.residency = HierarchicalResidency(
@@ -102,19 +110,23 @@ class NodeRuntime:
                 self._offload(m)
         if name not in self.device_params:
             self.device_params[name] = jax.tree.map(
-                jax.device_put, self.host_params[name])
+                lambda x: jax.device_put(x, self.device),
+                self.host_params[name])
             self.acc.register_weights(
                 name, self.profiles[name].weight_bytes)
             self.acc.register_context(name, self.ctx_bytes)
         if name not in self.engines:
-            self.engines[name] = Engine(
-                self.zoo[name], self.device_params[name], self.acc,
-                max_slots=self.max_slots, s_max=self.s_max,
-                arena=self.arena, prefix_cache=self.prefix_cfg,
-                prefix_ns=name,
-                max_batch_tokens=self.max_batch_tokens,
-                prefill_chunk_tokens=self.prefill_chunk_tokens,
-                decode_horizon=self.decode_horizon)
+            # the engine makes its state cache and arena planes under the
+            # default device: pin that to this node's
+            with jax.default_device(self.device):
+                self.engines[name] = Engine(
+                    self.zoo[name], self.device_params[name], self.acc,
+                    max_slots=self.max_slots, s_max=self.s_max,
+                    arena=self.arena, prefix_cache=self.prefix_cfg,
+                    prefix_ns=name,
+                    max_batch_tokens=self.max_batch_tokens,
+                    prefill_chunk_tokens=self.prefill_chunk_tokens,
+                    decode_horizon=self.decode_horizon)
         else:
             self.engines[name].params = self.device_params[name]
         return time.perf_counter() - t0
@@ -128,6 +140,7 @@ class NodeRuntime:
         eng = self.engines.get(name)
         if eng is not None:
             eng.release_kv()
+            eng.params = None     # the engine's reference would pin them
         self.device_params.pop(name, None)
         self.acc.unregister_weights(name)
         if self.residency.state[name] is ModelState.CPU:
@@ -245,7 +258,10 @@ class NodeRuntime:
             if (eng.waiting or eng.active) and name not in self.device_params:
                 self.activate(name)   # self-heal: offloaded with queued work
             if name in self.device_params and (eng.waiting or eng.active):
-                eng.step()
+                # step inputs, plane growth and state caches go to this
+                # node's device
+                with jax.default_device(self.device):
+                    eng.step()
             if eng.finished:
                 out[name] = eng.finished[:]
                 eng.finished.clear()

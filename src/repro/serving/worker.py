@@ -49,6 +49,7 @@ import argparse
 import collections
 import dataclasses
 import multiprocessing as mp
+import os
 import time
 import traceback
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -67,6 +68,47 @@ _BOOT_TIMEOUT_S = 60.0
 #: when the request/reply method set changes (the frame format has its own
 #: independent version, ``transport.FRAME_VERSION``)
 PROTOCOL_VERSION = 1
+
+
+class ChipUnavailableError(RuntimeError):
+    """A local worker child would need a TPU chip that it cannot have. A
+    chip belongs to one process at a time, and a JAX process claims every
+    chip it can see: a parent that has touched JAX holds them all, and two
+    children cannot share them. Worker backends are for CPU runs and for
+    workers on other hosts; on a chip, serve in one process
+    (``backend="inproc"``)."""
+
+
+def _host_tpu_chips() -> int:
+    """TPU chips a spawned child would try to claim: the host's, or 0 when
+    there are none or children run on the CPU (``JAX_PLATFORMS`` without
+    "tpu", as tests and CI set it). Reads the PCI bus, not a JAX backend."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return 0
+    from jax._src import hardware_utils
+    return hardware_utils.num_available_tpu_chips_and_device_id()[0]
+
+
+def check_local_chips(n_children: int) -> None:
+    """Fail fast, before any child starts, where ``n_children`` local
+    workers cannot each get the chips they would claim."""
+    n_chips = _host_tpu_chips()
+    if not (n_chips and n_children):
+        return
+    from jax._src import xla_bridge
+    if xla_bridge.backends_are_initialized() \
+            and "tpu" in xla_bridge.backends():
+        raise ChipUnavailableError(
+            f"this process holds the host's {n_chips} TPU chip(s), so "
+            f"{n_children} local worker(s) could not open them: serve "
+            f"in-process (backend='inproc')")
+    if n_children > 1:
+        raise ChipUnavailableError(
+            f"{n_children} local workers on a host with {n_chips} TPU "
+            f"chip(s): each JAX process claims every chip it can see, so "
+            f"only one of them could start: serve in-process "
+            f"(backend='inproc')")
 
 
 class WorkerDied(RuntimeError):
@@ -126,15 +168,31 @@ def _worker_main(conn, spec: WorkerSpec) -> None:
     compute_wall_s)`` with kind in {"ok", "prompt_too_long", "err"};
     ``compute_wall_s`` is the child-measured time spent executing the
     method, so the parent can charge only the residual (pipe + pickle) to
-    its IPC-overhead counter. Boot replies are ``("ready"|"boot_error",
-    payload)``."""
+    its IPC-overhead counter. Boot replies are ``("ready"|"boot_error"|
+    "no_chip", payload)``."""
     try:
         if spec.xla_flags:
             # must land before the child's first computation (the XLA
             # client parses XLA_FLAGS when it is created, not at import)
-            import os
             os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                                        + " " + spec.xla_flags).strip()
+        import jax
+        n_chips = _host_tpu_chips()
+        if n_chips:
+            try:
+                on_tpu = jax.default_backend() == "tpu"
+            except RuntimeError:          # libtpu refused: chip held
+                on_tpu = False
+            if not on_tpu:
+                # another process holds the chip: never serve on the CPU
+                # in its place
+                conn.send(("no_chip",
+                           f"node {spec.node_id}: the host has {n_chips} "
+                           f"TPU chip(s) but this worker could not open one "
+                           f"(another process holds them)"))
+                return
+        from repro.compile_cache import enable_compile_cache
+        enable_compile_cache()
         from repro.serving.cluster import build_zoo
         from repro.serving.node_runtime import NodeRuntime
         zoo, host = build_zoo(spec.model_names, seed=spec.seed)
@@ -337,6 +395,9 @@ class NodeHandle:
                 f"node {self.node_id} worker died during boot "
                 f"({self._exit_status()}); note: spawn re-imports "
                 f"the parent __main__, which must be an importable file")
+        if kind == "no_chip":
+            self.close()
+            raise ChipUnavailableError(payload)
         if kind != "ready":
             self.close()
             raise RuntimeError(
@@ -809,12 +870,15 @@ def spawn_fleet(specs: Sequence[WorkerSpec],
     before any ready handshake is awaited, so fleet boot costs the slowest
     node, not the sum. If any constructor or handshake fails, every
     already-started worker is torn down before the error propagates — a
-    failed spawn leaks no processes."""
+    failed spawn leaks no processes. On a TPU host, more workers than
+    there are chips for raise :class:`ChipUnavailableError` before any
+    starts."""
     try:
         cls = _HANDLE_CLASSES[backend]
     except KeyError:
         raise ValueError(f"unknown worker backend {backend!r} "
                          f"(expected one of {sorted(_HANDLE_CLASSES)})")
+    check_local_chips(len(specs))
     ctx = mp.get_context("spawn")
     handles: List[NodeHandle] = []
     try:

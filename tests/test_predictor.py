@@ -110,3 +110,23 @@ def test_ablation_direction(small_trace):
     mae_nosem = regression_metrics(
         y, no_sem.predict([s.obs for s in test])["length"])["mae"]
     assert mae_full <= mae_nosem * 1.05
+
+
+def test_hardware_spec_is_keyed_by_device_kind():
+    """Peaks come from the device's kind; the CPU keeps the default spec
+    (virtual-clock baselines do not move); an unknown TPU kind raises."""
+    import types
+
+    import jax
+
+    from repro.core.predictor.cost_model import (DEVICE_SPECS, HardwareSpec,
+                                                 UnknownDeviceError,
+                                                 hardware_spec)
+    assert hardware_spec(jax.devices("cpu")[0]) == HardwareSpec()
+    v5e = hardware_spec(types.SimpleNamespace(platform="tpu",
+                                              device_kind="TPU v5 lite"))
+    assert v5e is DEVICE_SPECS["TPU v5 lite"]
+    assert (v5e.peak_flops, v5e.hbm_bw) == (197e12, 819e9)
+    with pytest.raises(UnknownDeviceError, match="TPU v99"):
+        hardware_spec(types.SimpleNamespace(platform="tpu",
+                                            device_kind="TPU v99"))

@@ -1,4 +1,6 @@
 """Serving engine + node runtime integration (real JAX execution, tiny models)."""
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -154,3 +156,96 @@ def test_node_runtime_colocation_and_warm_reactivation():
     sig = node.signal()
     assert sig.headroom > 0
     assert "qwen3-8b" in sig.warm_models
+
+
+@pytest.mark.parametrize("chunk", [0, 8])
+def test_request_logits_are_the_rows_tokens_were_picked_from(tiny_model,
+                                                             chunk):
+    """A request that asks for logits gets one f32 row per host-picked
+    token (prefill or chunk, then each one-token decode), and each row's
+    argmax is the token; a request that does not ask keeps none."""
+    cfg, m, params = tiny_model
+    eng = Engine(m, params, MemoryAccountant(m_total=256e6), max_slots=2,
+                 s_max=64, prefill_chunk_tokens=chunk)
+    rng = np.random.default_rng(1)
+    asked = Request(req_id=0, tokens=list(rng.integers(0, cfg.vocab, 20)),
+                    max_new=5, logits=[])
+    plain = Request(req_id=1, tokens=list(rng.integers(0, cfg.vocab, 9)),
+                    max_new=5)
+    eng.submit(asked)
+    eng.submit(plain)
+    eng.drain()
+    assert plain.logits is None
+    assert len(asked.logits) == len(asked.out) == 5
+    rows = np.stack([np.asarray(r) for r in asked.logits])
+    assert rows.dtype == np.float32 and rows.shape[1] == m.vocab_padded
+    assert list(rows.argmax(axis=-1)) == asked.out
+
+
+def test_offload_releases_the_engines_weights():
+    """A slept model's weights are no longer referenced by its engine (they
+    would stay on the device), and reactivation gives them back."""
+    cfg = get_config("qwen3-8b").reduced()
+    mm = build_model(cfg)
+    host = {"qwen3-8b": jax.tree.map(np.asarray,
+                                     mm.init(jax.random.PRNGKey(1)))}
+    node = NodeRuntime(0, 0, {"qwen3-8b": mm}, host, hbm_budget=1e9,
+                       max_slots=2, s_max=48)
+    node.activate("qwen3-8b")
+    eng = node.engines["qwen3-8b"]
+    assert eng.params is node.device_params["qwen3-8b"]
+    node.sleep("qwen3-8b")
+    assert eng.params is None
+    node.activate("qwen3-8b")
+    assert eng.params is node.device_params["qwen3-8b"]
+
+
+def test_host_params_are_numpy_drawn_on_the_cpu():
+    from repro.serving.cluster import host_params
+    m = build_model(get_config("qwen3-8b").reduced())
+    host = host_params(m, 3)
+    ref = m.init(jax.random.PRNGKey(3))
+    for a, b in zip(jax.tree.leaves(host), jax.tree.leaves(ref)):
+        assert isinstance(a, np.ndarray)
+        np.testing.assert_array_equal(a, np.asarray(b))
+
+
+def test_fleet_nodes_each_hold_their_state_on_their_own_device():
+    """On a host with two devices, node i sits on device i: its weights,
+    arena planes and step outputs live there (CPU devices stand in for
+    chips, in a child process so the device count can be set)."""
+    import subprocess
+    import sys
+    import textwrap
+    code = textwrap.dedent("""
+        import jax
+        from repro.serving.cluster import ClusterSpec, NodeSpec, build_fleet
+        from repro.serving.engine import Request
+        spec = ClusterSpec(nodes=(NodeSpec(0, max_slots=2, s_max=48),
+                                  NodeSpec(1, max_slots=2, s_max=48)),
+                           model_names=("qwen3-8b",))
+        fleet = build_fleet(spec)
+        devs = jax.local_devices()
+        assert len(devs) == 2
+        for i, node in enumerate(fleet):
+            assert node.device == devs[i]
+            node.submit("qwen3-8b", Request(req_id=i, tokens=[3, 4, 5],
+                                            max_new=3))
+            out = {}
+            for _ in range(6):
+                out.update(node.step())
+            assert len(out["qwen3-8b"][0].out) == 3
+            leaves = jax.tree.leaves(node.device_params["qwen3-8b"])
+            assert {d for x in leaves for d in x.devices()} == {devs[i]}
+            for plane in node.arena.planes.values():
+                assert plane.k.devices() == plane.v.devices() == {devs[i]}
+        print("OK")
+    """)
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=2",
+               PYTHONPATH=os.pathsep.join(
+                   [os.path.join(os.path.dirname(__file__), "..", "src"),
+                    os.environ.get("PYTHONPATH", "")]))
+    r = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0 and r.stdout.strip().endswith("OK"), r.stderr

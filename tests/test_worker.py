@@ -185,3 +185,44 @@ def test_process_backend_requires_worker_fleet(zoo_host=None):
     with pytest.raises(ValueError, match="backend"):
         build_fleet(ClusterSpec(nodes=(NodeSpec(0),), rtt_s=RTT,
                                 model_names=ZOO_NAMES), backend="threads")
+
+
+def test_local_workers_fail_fast_where_chips_cannot_go_round(monkeypatch):
+    """On a TPU host, more local workers than there are chips for them
+    raise a typed error before any child starts; CPU runs are untouched."""
+    from jax._src import xla_bridge
+    from repro.serving import worker
+    specs = [WorkerSpec(node_id=i, cluster_id=0, model_names=ZOO_NAMES)
+             for i in range(2)]
+    # JAX_PLATFORMS=cpu, as tests and CI run: children stay on the CPU
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert worker._host_tpu_chips() == 0
+    worker.check_local_chips(8)
+    # a host with four chips: every child would claim all of them
+    monkeypatch.setattr(worker, "_host_tpu_chips", lambda: 4)
+    worker.check_local_chips(1)
+    with pytest.raises(worker.ChipUnavailableError, match="2 local workers"):
+        spawn_fleet(specs, backend="process")
+    with pytest.raises(worker.ChipUnavailableError):
+        spawn_fleet(specs, backend="socket")
+    assert not mp.active_children()
+    # ... and a parent that holds them leaves none for even one child
+    monkeypatch.setattr(xla_bridge, "backends_are_initialized", lambda: True)
+    monkeypatch.setattr(xla_bridge, "backends", lambda: {"tpu": None})
+    with pytest.raises(worker.ChipUnavailableError, match="holds"):
+        worker.check_local_chips(1)
+
+
+def test_worker_that_finds_no_chip_boots_into_a_typed_error():
+    """A child on a TPU host that cannot open a chip answers its boot with
+    ``no_chip`` instead of serving on the CPU; the handle raises it typed."""
+    from repro.serving import worker
+    h = NodeHandle.__new__(NodeHandle)
+    h._init_state(WorkerSpec(node_id=3, cluster_id=0,
+                             model_names=ZOO_NAMES))
+    h.proc = None
+    h._conn, child = mp.Pipe()
+    child.send(("no_chip", "node 3: the chip is held"))
+    with pytest.raises(worker.ChipUnavailableError, match="node 3"):
+        h.wait_ready()
+    child.close()
