@@ -40,6 +40,8 @@ import heapq
 import time
 from typing import Any, Callable, List, Optional, Protocol, runtime_checkable
 
+from repro.serving.telemetry import span
+
 #: Slack applied when deciding an event is due on the virtual clock — the
 #: same epsilon the old per-tick ``submit_at > now + 1e-9`` scan used.
 EPS = 1e-9
@@ -234,7 +236,8 @@ class WallClock:
             return                 # caller did real work this pass: free-run
         delay = until - self.now()
         if delay > 0:
-            self._sleep(min(delay, MAX_WALL_SLEEP_S))
+            with span("gateway.sleep"):
+                self._sleep(min(delay, MAX_WALL_SLEEP_S))
 
     def restart(self) -> None:
         """Re-zero the epoch: wall time restarts at 0 NOW. The gateway

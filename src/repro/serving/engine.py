@@ -45,6 +45,7 @@ from repro.kernels import paged_attention as _pa
 from repro.kernels import ref as _ref
 from repro.models.transformer import Model
 from repro.serving.kv_arena import KVArena
+from repro.serving.telemetry import span
 
 
 def _model_jit(model: "Model", key: tuple, builder):
@@ -271,6 +272,10 @@ class Engine:
         # OR per horizon launch) — host_syncs_per_token = syncs / tokens
         self.stat_horizon_steps = 0
         self.stat_decode_syncs = 0
+        # chunk programs launched and the real prompt tokens they ran (the
+        # rest of each [max_slots, chunk_tokens] launch is padding)
+        self.stat_chunk_launches = 0
+        self.stat_chunk_tokens = 0
         self.finished: List[Request] = []
 
     # -------------------------------------------------------------- state
@@ -371,8 +376,9 @@ class Engine:
         decoding)."""
         from repro.serving.prefix_cache import page_digests
         name = self._pc_ns
-        digs = page_digests(req.tokens, self.page_tokens, name)
-        m = self._pc.match(name, digs, req.tokens, self.page_tokens)
+        with span("engine.prefix_lookup", req=req.req_id):
+            digs = page_digests(req.tokens, self.page_tokens, name)
+            m = self._pc.match(name, digs, req.tokens, self.page_tokens)
         P = len(req.tokens)
         if m.n_full_tokens >= P:          # whole prompt cached: keep 1 page
             m.rows.pop()
@@ -527,15 +533,16 @@ class Engine:
         from repro.serving.prefix_cache import page_digests, root_key
         name = self._pc_ns
         page = self.page_tokens
-        if digs is None:
-            digs = page_digests(req.tokens, page, name)
-        rows = self.binding.seq_rows(req.req_id)
-        parent = root_key(name)
-        for i, d in enumerate(digs):
-            self._pc.insert(name, d, parent, self.binding.plane, rows[i],
-                            req.tokens[i * page:(i + 1) * page],
-                            n_prefix_tokens=(i + 1) * page)
-            parent = d
+        with span("engine.publish", req=req.req_id):
+            if digs is None:
+                digs = page_digests(req.tokens, page, name)
+            rows = self.binding.seq_rows(req.req_id)
+            parent = root_key(name)
+            for i, d in enumerate(digs):
+                self._pc.insert(name, d, parent, self.binding.plane, rows[i],
+                                req.tokens[i * page:(i + 1) * page],
+                                n_prefix_tokens=(i + 1) * page)
+                parent = d
 
     def _prefill_chunk_batch(self, rids: List[int]) -> None:
         """One fused chunk forward for the given mid-prefill sequences: each
@@ -547,38 +554,45 @@ class Engine:
         whose chunk reaches the end of its prompt gets its first output
         token from that chunk's last-row logits and joins decode at the NEXT
         iteration (join-at-iteration-granularity)."""
-        self._ensure_cache()
-        C = self.chunk_tokens
-        page = self.page_tokens
-        toks = np.zeros((self.max_slots, C), np.int32)
-        pos = np.zeros((self.max_slots, C), np.int32)
-        rows = np.zeros((self.max_slots, C), np.int32)
-        offs = np.zeros((self.max_slots, C), np.int32)
-        bt = np.zeros((self.max_slots, self.binding.bt_width), np.int32)
-        last_idx = np.zeros(self.max_slots, np.int32)
-        for rid in rids:
-            req = self.active[rid]
-            slot = self.slot_of[rid]
-            p0 = self._prefill_pos[rid]
-            n = min(C, len(req.tokens) - p0)
-            table = self.binding.row_table(rid)
-            bt[slot] = table
-            abs_t = np.arange(p0, p0 + n)
-            toks[slot, :n] = req.tokens[p0:p0 + n]
-            pos[slot, :n] = abs_t
-            rows[slot, :n] = table[abs_t // page]
-            offs[slot, :n] = abs_t % page
-            last_idx[slot] = n - 1
-            self._prefill_pos[rid] = p0 + n
-            self.stat_prefill_tokens += n
-        self._note_prefill_shape(("chunk", C))
-        self._tables_dirty = True
-        plane = self.binding.plane
-        tok_dev, logits, plane.k, plane.v = self._chunk_fwd(
-            self.params, plane.k, plane.v, jnp.asarray(toks),
-            jnp.asarray(pos), jnp.asarray(bt), jnp.asarray(rows),
-            jnp.asarray(offs), jnp.asarray(last_idx))
-        nxt = np.asarray(tok_dev)
+        with span("engine.chunk", lanes=len(rids)) as sp:
+            self._ensure_cache()
+            C = self.chunk_tokens
+            page = self.page_tokens
+            toks = np.zeros((self.max_slots, C), np.int32)
+            pos = np.zeros((self.max_slots, C), np.int32)
+            rows = np.zeros((self.max_slots, C), np.int32)
+            offs = np.zeros((self.max_slots, C), np.int32)
+            bt = np.zeros((self.max_slots, self.binding.bt_width), np.int32)
+            last_idx = np.zeros(self.max_slots, np.int32)
+            ran = 0
+            for rid in rids:
+                req = self.active[rid]
+                slot = self.slot_of[rid]
+                p0 = self._prefill_pos[rid]
+                n = min(C, len(req.tokens) - p0)
+                table = self.binding.row_table(rid)
+                bt[slot] = table
+                abs_t = np.arange(p0, p0 + n)
+                toks[slot, :n] = req.tokens[p0:p0 + n]
+                pos[slot, :n] = abs_t
+                rows[slot, :n] = table[abs_t // page]
+                offs[slot, :n] = abs_t % page
+                last_idx[slot] = n - 1
+                self._prefill_pos[rid] = p0 + n
+                ran += n
+            sp.set_metadata(tokens=ran)
+            self.stat_prefill_tokens += ran
+            self.stat_chunk_tokens += ran
+            self.stat_chunk_launches += 1
+            self._note_prefill_shape(("chunk", C))
+            self._tables_dirty = True
+            plane = self.binding.plane
+            tok_dev, logits, plane.k, plane.v = self._chunk_fwd(
+                self.params, plane.k, plane.v, jnp.asarray(toks),
+                jnp.asarray(pos), jnp.asarray(bt), jnp.asarray(rows),
+                jnp.asarray(offs), jnp.asarray(last_idx))
+            with span("engine.chunk.sync"):
+                nxt = np.asarray(tok_dev)
         for rid in rids:
             req = self.active[rid]
             if self._prefill_pos[rid] < len(req.tokens):
@@ -598,61 +612,76 @@ class Engine:
         """One fused engine iteration; returns the requests that finished
         DURING THIS CALL only (the accumulated history stays on
         ``self.finished`` for owners that drain it wholesale)."""
-        n0 = len(self.finished)
-        self.stat_steps += 1
-        for req in self._admit():
-            if self.chunk_tokens:
-                self._begin_chunked(req)
-            else:
-                self._prefill(req)
-        # sequences still streaming their prompt join decode at the NEXT
-        # iteration after their final chunk — snapshot the decode set first
-        decode_rids = [rid for rid in self.active
-                       if rid not in self._prefill_pos]
-        # mixed prefill+decode iterations fall back to one-token decode so
-        # chunked-prefill fusion semantics stay untouched; pure-decode
-        # iterations launch the on-device horizon
-        use_horizon = self.horizon > 1 and not self._prefill_pos
-        caps: Dict[int, int] = {}
-        if decode_rids and self.paged:
-            # grow page coverage for this step's token writes; a sequence
-            # the pool cannot extend finishes truncated (honest
-            # backpressure instead of silent overflow)
-            for rid in list(decode_rids):
-                pos = int(self.positions[self.slot_of[rid]])
-                if use_horizon:
-                    # pre-grant up to a horizon's worth of pages; a partial
-                    # grant caps that lane's emission budget (it stays
-                    # active and retries next step), a zero grant truncates
-                    # exactly like the one-token path
-                    req = self.active[rid]
-                    want = min(self.horizon, req.max_new - len(req.out),
-                               self.s_max - 1 - pos)
-                    got = self._pregrant(rid, pos, want)
-                    if got > 0:
-                        caps[rid] = got
-                        continue
-                elif self.binding.ensure_tokens(rid, pos + 1):
+        with span("engine.step"):
+            n0 = len(self.finished)
+            self.stat_steps += 1
+            with span("engine.admit", waiting=len(self.waiting)) as sp:
+                admitted = self._admit()
+                sp.set_metadata(admitted=len(admitted))
+            for req in admitted:
+                if self.chunk_tokens:
+                    self._begin_chunked(req)
+                else:
+                    self._prefill(req)
+            # sequences still streaming their prompt join decode at the NEXT
+            # iteration after their final chunk — snapshot the decode set
+            decode_rids = [rid for rid in self.active
+                           if rid not in self._prefill_pos]
+            # mixed prefill+decode iterations fall back to one-token decode
+            # so chunked-prefill fusion semantics stay untouched;
+            # pure-decode iterations launch the on-device horizon
+            use_horizon = self.horizon > 1 and not self._prefill_pos
+            caps: Dict[int, int] = {}
+            if decode_rids and self.paged:
+                with span("engine.pregrant", lanes=len(decode_rids)):
+                    self._grow_pages(decode_rids, use_horizon, caps)
+            if self._prefill_pos:
+                # token-budget split: decode contributes one position per
+                # sequence, the remainder admits whole prefill chunks; at
+                # least one chunk always advances (prefill cannot starve)
+                if self.max_batch_tokens is None:
+                    n_adv = len(self._prefill_pos)
+                else:
+                    room = self.max_batch_tokens - len(decode_rids)
+                    n_adv = max(room // self.chunk_tokens, 1)
+                advance = list(self._prefill_pos)[:n_adv]
+                self._prefill_chunk_batch(advance)
+                if decode_rids:
+                    self.stat_fused_steps += 1
+            if decode_rids and use_horizon and self.paged:
+                self._decode_horizon_batch(decode_rids, caps)
+            elif decode_rids:
+                self._decode_one(decode_rids)
+            return self.finished[n0:]
+
+    def _grow_pages(self, decode_rids: List[int], use_horizon: bool,
+                    caps: Dict[int, int]) -> None:
+        """Grow page coverage for this step's token writes; a sequence the
+        pool cannot extend finishes truncated (honest backpressure instead
+        of silent overflow) and leaves ``decode_rids``."""
+        for rid in list(decode_rids):
+            pos = int(self.positions[self.slot_of[rid]])
+            if use_horizon:
+                # pre-grant up to a horizon's worth of pages; a partial
+                # grant caps that lane's emission budget (it stays active
+                # and retries next step), a zero grant truncates exactly
+                # like the one-token path
+                req = self.active[rid]
+                want = min(self.horizon, req.max_new - len(req.out),
+                           self.s_max - 1 - pos)
+                got = self._pregrant(rid, pos, want)
+                if got > 0:
+                    caps[rid] = got
                     continue
-                self.active[rid].truncated = True
-                self._release(rid)
-                decode_rids.remove(rid)
-        if self._prefill_pos:
-            # token-budget split: decode contributes one position per
-            # sequence, the remainder admits whole prefill chunks; at least
-            # one chunk always advances (prefill cannot starve)
-            if self.max_batch_tokens is None:
-                n_adv = len(self._prefill_pos)
-            else:
-                room = self.max_batch_tokens - len(decode_rids)
-                n_adv = max(room // self.chunk_tokens, 1)
-            advance = list(self._prefill_pos)[:n_adv]
-            self._prefill_chunk_batch(advance)
-            if decode_rids:
-                self.stat_fused_steps += 1
-        if decode_rids and use_horizon and self.paged:
-            self._decode_horizon_batch(decode_rids, caps)
-        elif decode_rids:
+            elif self.binding.ensure_tokens(rid, pos + 1):
+                continue
+            self.active[rid].truncated = True
+            self._release(rid)
+            decode_rids.remove(rid)
+
+    def _decode_one(self, decode_rids: List[int]) -> None:
+        """One-token decode of every decoding lane, launch to argmax sync."""
+        with span("engine.decode", lanes=len(decode_rids)):
             self._ensure_cache()
             toks = np.zeros((self.max_slots, 1), np.int32)
             for rid in decode_rids:
@@ -663,25 +692,30 @@ class Engine:
                 logits, self.cache = self._decode(
                     self.params, self.cache, jnp.asarray(toks),
                     jnp.asarray(self.positions))
-            nxt = np.asarray(jnp.argmax(logits, axis=-1))
-            self.stat_decode_syncs += 1
-            self.stat_decode_tokens += len(decode_rids)
-            self._tables_dirty = True
-            done = []
-            for rid in decode_rids:
-                req = self.active[rid]
-                slot = self.slot_of[rid]
-                tok = int(nxt[slot])
-                self._keep_logits(req, logits, slot)
-                req.out.append(tok)
-                self.positions[slot] += 1
-                if (len(req.out) >= req.max_new
-                        or (req.eos is not None and tok == req.eos)
-                        or self.positions[slot] >= self.s_max - 1):
-                    done.append(rid)
-            for rid in done:
-                self._release(rid)
-        return self.finished[n0:]
+            with span("engine.decode.sync"):
+                nxt = np.asarray(jnp.argmax(logits, axis=-1))
+        self.stat_decode_syncs += 1
+        self.stat_decode_tokens += len(decode_rids)
+        self._tables_dirty = True
+        done = []
+        for rid in decode_rids:
+            req = self.active[rid]
+            slot = self.slot_of[rid]
+            tok = int(nxt[slot])
+            self._keep_logits(req, logits, slot)
+            req.out.append(tok)
+            self.positions[slot] += 1
+            if (len(req.out) >= req.max_new
+                    or (req.eos is not None and tok == req.eos)
+                    or self.positions[slot] >= self.s_max - 1):
+                done.append(rid)
+        self._release_done(done)
+
+    def _release_done(self, done: List[int]) -> None:
+        if done:
+            with span("engine.release"):
+                for rid in done:
+                    self._release(rid)
 
     def _pregrant(self, rid: int, pos: int, want: int) -> int:
         """Pre-grant pages for up to ``want`` horizon writes starting at
@@ -722,37 +756,39 @@ class Engine:
         release finished requests (boundary preemption granularity becomes
         the horizon launch, measured — not asserted — in
         ``benchmarks/decode_horizon.py``)."""
-        self._ensure_cache()
-        B = self.max_slots
-        live = np.zeros(B, bool)
-        last = np.zeros(B, np.int32)
-        rem = np.ones(B, np.int32)
-        cap = np.zeros(B, np.int32)
-        eos = np.full(B, -1, np.int32)
-        for rid in decode_rids:
-            slot = self.slot_of[rid]
-            req = self.active[rid]
-            live[slot] = True
-            last[slot] = req.out[-1]
-            rem[slot] = req.max_new - len(req.out)
-            cap[slot] = caps[rid]
-            if req.eos is not None:
-                eos[slot] = req.eos
-        if self._tables_dirty or self._dev_bt is None:
-            bt = np.zeros((B, self.binding.bt_width), np.int32)
+        with span("engine.horizon", lanes=len(decode_rids)):
+            self._ensure_cache()
+            B = self.max_slots
+            live = np.zeros(B, bool)
+            last = np.zeros(B, np.int32)
+            rem = np.ones(B, np.int32)
+            cap = np.zeros(B, np.int32)
+            eos = np.full(B, -1, np.int32)
             for rid in decode_rids:
-                bt[self.slot_of[rid]] = self.binding.row_table(rid)
-            self._dev_bt = jnp.asarray(bt)
-            self._dev_pos = jnp.asarray(self.positions)
-            self._tables_dirty = False
-        plane = self.binding.plane
-        tok_blk, self._dev_pos, self.cache, plane.k, plane.v = \
-            self._horizon_fwd(
-                self.params, self.cache, plane.k, plane.v, self._dev_bt,
-                self._dev_pos, jnp.asarray(last), jnp.asarray(live),
-                jnp.asarray(rem), jnp.asarray(cap), jnp.asarray(eos),
-                jnp.int32(self.s_max))
-        blk = np.asarray(tok_blk)               # the ONE host sync
+                slot = self.slot_of[rid]
+                req = self.active[rid]
+                live[slot] = True
+                last[slot] = req.out[-1]
+                rem[slot] = req.max_new - len(req.out)
+                cap[slot] = caps[rid]
+                if req.eos is not None:
+                    eos[slot] = req.eos
+            if self._tables_dirty or self._dev_bt is None:
+                bt = np.zeros((B, self.binding.bt_width), np.int32)
+                for rid in decode_rids:
+                    bt[self.slot_of[rid]] = self.binding.row_table(rid)
+                self._dev_bt = jnp.asarray(bt)
+                self._dev_pos = jnp.asarray(self.positions)
+                self._tables_dirty = False
+            plane = self.binding.plane
+            tok_blk, self._dev_pos, self.cache, plane.k, plane.v = \
+                self._horizon_fwd(
+                    self.params, self.cache, plane.k, plane.v, self._dev_bt,
+                    self._dev_pos, jnp.asarray(last), jnp.asarray(live),
+                    jnp.asarray(rem), jnp.asarray(cap), jnp.asarray(eos),
+                    jnp.int32(self.s_max))
+            with span("engine.horizon.sync"):
+                blk = np.asarray(tok_blk)       # the ONE host sync
         self.stat_decode_syncs += 1
         self.stat_horizon_steps += 1
         done = []
@@ -771,8 +807,7 @@ class Engine:
                         or self.positions[slot] >= self.s_max - 1):
                     done.append(rid)
                     break
-        for rid in done:
-            self._release(rid)
+        self._release_done(done)
 
     def _decode_paged(self, toks: np.ndarray, decode_rids: List[int]):
         """One paged decode step: build block tables / write coordinates for

@@ -66,7 +66,7 @@ from repro.serving.node_runtime import NodeRuntime
 from repro.serving.prefix_cache import page_digests
 from repro.serving.registry import FleetRegistry, HeartbeatConfig
 from repro.serving.telemetry import (GatewayMetrics, NodeDeathEvent,
-                                     Telemetry)
+                                     Telemetry, span)
 from repro.serving.worker import WorkerDied, close_fleet
 
 COLD_START_THRESHOLD_S = 0.01
@@ -685,47 +685,51 @@ class ClusterGateway:
                     n.step()                    # warmup output discarded
 
     def step(self) -> None:
-        now = self.clock.now()
-        # 1) arrivals: source stages of newly arrived jobs become ready
-        while self.arrivals and self.arrivals[0][0] <= now:
-            _, jid = self.arrivals.pop(0)
-            for s in self.jobs[jid].stages:
-                if not s.deps:
-                    self._mark_ready(s, now)
-        # 2) membership sweep (wall clock + worker backends only): fold
-        # piggybacked heartbeats, ping silent nodes, age the liveness state
-        # machine, evacuate timeouts
-        if self._liveness_on and self._hb_cad.due():
-            self._membership_sweep(now)
-        # 3) aging refresh of the global queue (clock-owned cadence)
-        if self._refresh_cad.due():
-            self._q_refresh(now)
-        # 4) global-queue dispatch (routing + admission + preemption); a
-        # worker dying mid-decision surfaces typed and is evacuated here
-        try:
-            self._dispatch(now)
-        except WorkerDied as e:
-            self._on_node_death(e.node_id, now, cause=str(e))
-        # 5) transit releases: stages whose rtt + activation delay elapsed
-        # (scheduled as clock events at dispatch) hit their engines
-        self._fire_releases(now)
-        # 6) engine progress: lock-step under the virtual clock, polling of
-        # free-running workers / direct stepping under the wall clock
-        did_work = self._collect_finished(now)
-        # 7) telemetry sampling (reported signals when workers free-run —
-        # an accountant round trip would block on an engine-step boundary)
-        if self._headroom_cad.due():
-            for nid, node in list(self.fleet.items()):
-                try:
-                    sig = self._reported_signal(nid)
-                    self.telemetry.sample_headroom(
-                        nid, sig.headroom if sig is not None
-                        else node.acc.headroom)
-                except WorkerDied as e:
-                    self._on_node_death(e.node_id, now, cause=str(e))
-        # 8) advance time: one tick (virtual) or sleep until the next
-        # wake-up (wall; skipped when engines did real work this pass)
-        self.clock.advance(None if did_work else self._next_wake(now))
+        with span("gateway.step"):
+            now = self.clock.now()
+            # 1) arrivals: source stages of newly arrived jobs become ready
+            while self.arrivals and self.arrivals[0][0] <= now:
+                _, jid = self.arrivals.pop(0)
+                for s in self.jobs[jid].stages:
+                    if not s.deps:
+                        self._mark_ready(s, now)
+            # 2) membership sweep (wall clock + worker backends only): fold
+            # piggybacked heartbeats, ping silent nodes, age the liveness state
+            # machine, evacuate timeouts
+            if self._liveness_on and self._hb_cad.due():
+                self._membership_sweep(now)
+            # 3) aging refresh of the global queue (clock-owned cadence)
+            if self._refresh_cad.due():
+                with span("gateway.refresh", queued=len(self._queued)):
+                    self._q_refresh(now)
+            # 4) global-queue dispatch (routing + admission + preemption); a
+            # worker dying mid-decision surfaces typed and is evacuated here
+            try:
+                self._dispatch(now)
+            except WorkerDied as e:
+                self._on_node_death(e.node_id, now, cause=str(e))
+            # 5) transit releases: stages whose rtt + activation delay elapsed
+            # (scheduled as clock events at dispatch) hit their engines
+            with span("gateway.release"):
+                self._fire_releases(now)
+            # 6) engine progress: lock-step under the virtual clock, polling of
+            # free-running workers / direct stepping under the wall clock
+            with span("gateway.collect"):
+                did_work = self._collect_finished(now)
+            # 7) telemetry sampling (reported signals when workers free-run —
+            # an accountant round trip would block on an engine-step boundary)
+            if self._headroom_cad.due():
+                for nid, node in list(self.fleet.items()):
+                    try:
+                        sig = self._reported_signal(nid)
+                        self.telemetry.sample_headroom(
+                            nid, sig.headroom if sig is not None
+                            else node.acc.headroom)
+                    except WorkerDied as e:
+                        self._on_node_death(e.node_id, now, cause=str(e))
+            # 8) advance time: one tick (virtual) or sleep until the next
+            # wake-up (wall; skipped when engines did real work this pass)
+            self.clock.advance(None if did_work else self._next_wake(now))
 
     def _collect_finished(self, now: float) -> bool:
         """Drive engine progress and drain finished requests; returns True
@@ -825,6 +829,17 @@ class ClusterGateway:
         self._q_push(stage, now)
 
     def _dispatch(self, now: float) -> None:
+        with span("gateway.dispatch") as sp:
+            rejected0 = self.telemetry.admission_rejections
+            dispatched = self._dispatch_queue(now)
+            sp.set_metadata(dispatched=dispatched,
+                            rejected=self.telemetry.admission_rejections
+                            - rejected0)
+
+    def _dispatch_queue(self, now: float) -> int:
+        """Dispatch from the head of the global queue until it blocks or
+        empties; returns the number of stages dispatched."""
+        dispatched = 0
         while self._queued:
             stage = self._q_peek(now)
             if stage is None:
@@ -873,6 +888,9 @@ class ClusterGateway:
                 # queue (still not-yet-dispatched) and evacuate the node
                 self._q_push(stage, now)
                 self._on_node_death(e.node_id, now, cause=str(e))
+            else:
+                dispatched += 1
+        return dispatched
 
     def _dispatch_to(self, stage: LiveStage, nid: int, r_need: float,
                      now: float) -> None:
@@ -938,7 +956,6 @@ class ClusterGateway:
             # Alg. 2 cheap prefix (levels 1-2) executed live: sleep idle
             # engines / drop warm contexts so the reservation fits
             node.make_room(rec.r_need)
-        t0 = time.perf_counter()
         rec.submitted = True
         self.pending_resv[rec.node_id] -= rec.r_need
         if self.clock.name == "wall" and self.node_backend != "inproc":
@@ -949,7 +966,7 @@ class ClusterGateway:
             node.submit_send(rec.model, rec.req)
             ev = self.telemetry.event(rec.stage.stage_id, rec.stage.job_id,
                                       rec.stage.interactive)
-            ev.start_t = now          # wall_act_s unknown on the async path
+            ev.start_t = now
             return
         try:
             node.submit(rec.model, rec.req)   # real activation on demand
@@ -963,7 +980,6 @@ class ClusterGateway:
         ev = self.telemetry.event(rec.stage.stage_id, rec.stage.job_id,
                                   rec.stage.interactive)
         ev.start_t = now
-        ev.wall_act_s = time.perf_counter() - t0
 
     def _on_finish(self, req: Request, now: float) -> None:
         rec = self.inflight.pop(req.req_id, None)
@@ -974,42 +990,45 @@ class ClusterGateway:
 
     def _complete(self, stage: LiveStage, model: str, req: Request,
                   now: float) -> None:
-        self.done.add(stage.stage_id)
-        self._rejects.pop(stage.stage_id, None)
-        ev = self.telemetry.event(stage.stage_id, stage.job_id,
-                                  stage.interactive)
-        # telemetry's finished sentinel is finish_t > 0; dispatch-time
-        # truncation can legitimately land at exactly t=0, so clamp
-        ev.finish_t, ev.out_len = max(now, 1e-9), len(req.out)
-        ev.prompt_tokens = len(req.tokens)
-        ev.prefill_avoided = int(getattr(req, "prefill_avoided", 0))
-        ev.ttft_s = float(getattr(req, "ttft_s", 0.0))
-        # Calibrate on the SAME basis the prediction used (the uncapped
-        # trace-scale lengths): the realized output, mapped back through the
-        # live decode budget, against L_hat. Comparing live capped bytes to
-        # the uncapped R_kv_hat would make the error identically zero and
-        # pin rho to its floor.
-        if not req.truncated:
-            # truncated stages never ran to their true length — feeding
-            # their (near-zero) realized KV into calibration would record a
-            # phantom maximal overprediction and skew rho for real stages
-            prof = self.profiles[model]
-            nominal = stage.nominal_len or stage.max_new
-            actual_len = nominal * len(req.out) / max(stage.max_new, 1)
-            actual_kv = prof.r_kv(stage.obs.prompt_len, actual_len)
-            self.policy.on_finish(self, self.view(stage), actual_kv,
-                                  self.job_remaining_v(stage))
-        job = self.jobs[stage.job_id]
-        self.job_done_stages[stage.job_id] += 1
-        if self.job_done_stages[stage.job_id] == len(job.stages):
-            self.job_finish[stage.job_id] = now
-        # successor re-queueing: every dependent whose deps are all done
-        # re-enters the GLOBAL queue and contends under the policy's order
-        for st in job.stages:
-            if stage.stage_id in st.deps:
-                self.pending_deps[st.stage_id] -= 1
-                if self.pending_deps[st.stage_id] == 0:
-                    self._mark_ready(st, now)
+        with span("gateway.finish", req=stage.stage_id):
+            self.done.add(stage.stage_id)
+            self._rejects.pop(stage.stage_id, None)
+            ev = self.telemetry.event(stage.stage_id, stage.job_id,
+                                      stage.interactive)
+            # telemetry's finished sentinel is finish_t > 0; dispatch-time
+            # truncation can legitimately land at exactly t=0, so clamp
+            ev.finish_t, ev.out_len = max(now, 1e-9), len(req.out)
+            ev.prompt_tokens = len(req.tokens)
+            ev.prefill_avoided = int(getattr(req, "prefill_avoided", 0))
+            ev.ttft_s = float(getattr(req, "ttft_s", 0.0))
+            # Calibrate on the SAME basis the prediction used (the uncapped
+            # trace-scale lengths): the realized output, mapped back through
+            # the live decode budget, against L_hat. Comparing live capped
+            # bytes to the uncapped R_kv_hat would make the error
+            # identically zero and pin rho to its floor.
+            if not req.truncated:
+                # truncated stages never ran to their true length — feeding
+                # their (near-zero) realized KV into calibration would record
+                # a phantom maximal overprediction and skew rho for real
+                # stages
+                prof = self.profiles[model]
+                nominal = stage.nominal_len or stage.max_new
+                actual_len = nominal * len(req.out) / max(stage.max_new, 1)
+                actual_kv = prof.r_kv(stage.obs.prompt_len, actual_len)
+                self.policy.on_finish(self, self.view(stage), actual_kv,
+                                      self.job_remaining_v(stage))
+            job = self.jobs[stage.job_id]
+            self.job_done_stages[stage.job_id] += 1
+            if self.job_done_stages[stage.job_id] == len(job.stages):
+                self.job_finish[stage.job_id] = now
+            # successor re-queueing: every dependent whose deps are all
+            # done re-enters the GLOBAL queue and contends under the
+            # policy's order
+            for st in job.stages:
+                if stage.stage_id in st.deps:
+                    self.pending_deps[st.stage_id] -= 1
+                    if self.pending_deps[st.stage_id] == 0:
+                        self._mark_ready(st, now)
 
     # ---------------------------------------------------- membership plane
     def _membership_sweep(self, now: float) -> None:

@@ -28,6 +28,7 @@ from repro.core.sched.fitness import NodeSignal
 from repro.models.transformer import Model
 from repro.serving.engine import Engine, Request
 from repro.serving.kv_arena import KVArena
+from repro.serving.telemetry import span
 
 
 def _tree_bytes(tree) -> int:
@@ -94,42 +95,45 @@ class NodeRuntime:
     # ------------------------------------------------------------ residency
     def activate(self, name: str) -> float:
         """Make `name` servable; returns measured activation seconds."""
-        t0 = time.perf_counter()
-        # models with ANY queued work are in-flight: evicting one whose
-        # requests are still waiting for admission would strand them (step()
-        # skips off-device engines)
-        self.residency.pinned = {m for m, e in self.engines.items()
-                                 if e.active or e.waiting}
-        ok, _ = self.residency.ensure_gpu(name)
-        if not ok:
-            raise RuntimeError(f"cannot activate {name}")
-        # apply evictions the residency manager decided
-        for m, st in self.residency.state.items():
-            if st in (ModelState.SLEEPING, ModelState.CPU) \
-                    and m in self.device_params:
-                self._offload(m)
-        if name not in self.device_params:
-            self.device_params[name] = jax.tree.map(
-                lambda x: jax.device_put(x, self.device),
-                self.host_params[name])
-            self.acc.register_weights(
-                name, self.profiles[name].weight_bytes)
-            self.acc.register_context(name, self.ctx_bytes)
-        if name not in self.engines:
-            # the engine makes its state cache and arena planes under the
-            # default device: pin that to this node's
-            with jax.default_device(self.device):
-                self.engines[name] = Engine(
-                    self.zoo[name], self.device_params[name], self.acc,
-                    max_slots=self.max_slots, s_max=self.s_max,
-                    arena=self.arena, prefix_cache=self.prefix_cfg,
-                    prefix_ns=name,
-                    max_batch_tokens=self.max_batch_tokens,
-                    prefill_chunk_tokens=self.prefill_chunk_tokens,
-                    decode_horizon=self.decode_horizon)
-        else:
-            self.engines[name].params = self.device_params[name]
-        return time.perf_counter() - t0
+        moved = (0 if name in self.device_params
+                 else self.profiles[name].weight_bytes)
+        with span("node.activate", model=name, bytes=moved):
+            t0 = time.perf_counter()
+            # models with ANY queued work are in-flight: evicting one whose
+            # requests are still waiting for admission would strand them
+            # (step() skips off-device engines)
+            self.residency.pinned = {m for m, e in self.engines.items()
+                                     if e.active or e.waiting}
+            ok, _ = self.residency.ensure_gpu(name)
+            if not ok:
+                raise RuntimeError(f"cannot activate {name}")
+            # apply evictions the residency manager decided
+            for m, st in self.residency.state.items():
+                if st in (ModelState.SLEEPING, ModelState.CPU) \
+                        and m in self.device_params:
+                    self._offload(m)
+            if name not in self.device_params:
+                self.device_params[name] = jax.tree.map(
+                    lambda x: jax.device_put(x, self.device),
+                    self.host_params[name])
+                self.acc.register_weights(
+                    name, self.profiles[name].weight_bytes)
+                self.acc.register_context(name, self.ctx_bytes)
+            if name not in self.engines:
+                # the engine makes its state cache and arena planes under the
+                # default device: pin that to this node's
+                with jax.default_device(self.device):
+                    self.engines[name] = Engine(
+                        self.zoo[name], self.device_params[name], self.acc,
+                        max_slots=self.max_slots, s_max=self.s_max,
+                        arena=self.arena, prefix_cache=self.prefix_cfg,
+                        prefix_ns=name,
+                        max_batch_tokens=self.max_batch_tokens,
+                        prefill_chunk_tokens=self.prefill_chunk_tokens,
+                        decode_horizon=self.decode_horizon)
+            else:
+                self.engines[name].params = self.device_params[name]
+            return time.perf_counter() - t0
 
     def _offload(self, name: str) -> None:
         """Device -> host (weights only; jit executable cache survives —
@@ -152,9 +156,10 @@ class NodeRuntime:
 
     # -------------------------------------------------------------- serving
     def submit(self, model: str, req: Request) -> None:
-        if model not in self.device_params:
-            self.activate(model)
-        self.engines[model].submit(req)
+        with span("node.submit", node=self.node_id, req=req.req_id):
+            if model not in self.device_params:
+                self.activate(model)
+            self.engines[model].submit(req)
 
     def preempt(self, model: str, req_id: int) -> Optional[Request]:
         """Boundary-preempt a request on this node (waiting or active);
@@ -254,17 +259,19 @@ class NodeRuntime:
 
     def step(self) -> Dict[str, list]:
         out = {}
-        for name, eng in self.engines.items():
-            if (eng.waiting or eng.active) and name not in self.device_params:
-                self.activate(name)   # self-heal: offloaded with queued work
-            if name in self.device_params and (eng.waiting or eng.active):
-                # step inputs, plane growth and state caches go to this
-                # node's device
-                with jax.default_device(self.device):
-                    eng.step()
-            if eng.finished:
-                out[name] = eng.finished[:]
-                eng.finished.clear()
+        with span("node.step", node=self.node_id):
+            for name, eng in self.engines.items():
+                if (eng.waiting or eng.active) \
+                        and name not in self.device_params:
+                    self.activate(name)   # self-heal: offloaded, work queued
+                if name in self.device_params and (eng.waiting or eng.active):
+                    # step inputs, plane growth and state caches go to this
+                    # node's device
+                    with jax.default_device(self.device):
+                        eng.step()
+                if eng.finished:
+                    out[name] = eng.finished[:]
+                    eng.finished.clear()
         return out
 
     # -------------------------------------------------------------- signals
@@ -305,7 +312,13 @@ class NodeRuntime:
                "engine_horizon_steps": sum(
                    e.stat_horizon_steps for e in self.engines.values()),
                "engine_decode_syncs": sum(
-                   e.stat_decode_syncs for e in self.engines.values())}
+                   e.stat_decode_syncs for e in self.engines.values()),
+               # chunked-prefill fill: launches and the real prompt tokens
+               # they ran, of max_slots x prefill_chunk_tokens each
+               "engine_chunk_launches": sum(
+                   e.stat_chunk_launches for e in self.engines.values()),
+               "engine_chunk_tokens": sum(
+                   e.stat_chunk_tokens for e in self.engines.values())}
         if self.arena.prefix_index is not None:
             out.update(self.arena.prefix_index.stats())
         return out

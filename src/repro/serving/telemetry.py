@@ -4,20 +4,63 @@ SLO/latency aggregation for the LIVE serving plane.
 Times are in the gateway's CLOCK (``GatewayMetrics.clock`` records which):
 deterministic step-driven virtual seconds under the default virtual clock,
 real elapsed seconds under the wall clock — so wall-clock rows report queue
-delay and SLO attainment against real time. ``wall_act_s`` always records
-the real measured activation cost of the underlying ``NodeRuntime``
-(host->device transfer + engine construction) regardless of clock.
+delay and SLO attainment against real time.
 The summary mirrors ``repro.sim.simulator.SimResult`` so the live plane and
 the trace-driven simulator report the same policy-comparison columns.
+
+Spans: :func:`span` marks a stretch of host work at a layer boundary of the
+served path (``gateway.*``, ``node.*``, ``engine.*``). Spans are off unless
+:func:`enable_spans` is given an annotation factory such as
+``jax.profiler.TraceAnnotation``; the profiler then records each span as
+``maestro:<name>`` on the host timeline of the same trace as the chip's
+operations, on one clock. Spans that act on one request carry
+``req=<engine request id>`` (the gateway's stage id). Every span of the
+program goes through :func:`span`; no other module annotates the trace.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Tuple
+from typing import TYPE_CHECKING, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from repro.serving.clock import RunDeadlineExceeded
+if TYPE_CHECKING:
+    from repro.serving.clock import RunDeadlineExceeded
+
+
+class _NullSpan:
+    """What :func:`span` returns while spans are off: one shared object
+    that does nothing on enter, exit or :meth:`set_metadata`."""
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+    def set_metadata(self, **args) -> None:
+        pass
+
+
+NULL_SPAN = _NullSpan()
+_annotate: Optional[Callable] = None
+
+
+def enable_spans(annotate: Optional[Callable]) -> None:
+    """Turn spans on with ``annotate`` (called as ``annotate(name,
+    **args)``, a context manager with ``set_metadata(**args)``, such as
+    ``jax.profiler.TraceAnnotation``), or off with None, the default."""
+    global _annotate
+    _annotate = annotate
+
+
+def span(name: str, **args):
+    """A context manager around one stretch of host work, named
+    ``maestro:<name>`` on the trace; args known only at its end go through
+    ``set_metadata`` on what ``with`` binds. Off: :data:`NULL_SPAN`."""
+    if _annotate is None:
+        return NULL_SPAN
+    return _annotate("maestro:" + name, **args)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -45,7 +88,6 @@ class StageEvent:
     finish_t: float = 0.0         # engine emitted the final token
     rtt_s: float = 0.0
     t_act_s: float = 0.0          # virtual activation latency (residency est.)
-    wall_act_s: float = 0.0       # measured wall-clock activation
     out_len: int = 0
     prompt_tokens: int = 0        # live prompt length the engine prefetched
     prefill_avoided: int = 0      # prompt tokens served from the prefix cache

@@ -1,14 +1,28 @@
-"""Percentile edge cases + fault/tail columns in the telemetry plane.
+"""Percentile edge cases + fault/tail columns in the telemetry plane, and
+the program's spans and chunk counters.
 
 The fleet-summed benchmark paths serialize ``GatewayMetrics.row()`` straight
 into BENCH JSON: a NaN (np.percentile of an empty array) or an IndexError
 on a single-sample run would poison every downstream comparison, so the
 extreme-tail columns (p99/p99.9) are pinned to 0.0 below two samples."""
+import collections
 import math
 import types
 
+import jax
+import numpy as np
 import pytest
 
+from _stubs import StubPred
+from repro.configs import get_config
+from repro.core.predictor.features import StageObservation
+from repro.core.runtime.accounting import MemoryAccountant
+from repro.models import build_model
+from repro.serving import telemetry
+from repro.serving.cluster import (ClusterSpec, LiveJob, LiveStage, NodeSpec,
+                                   build_fleet, build_zoo)
+from repro.serving.engine import Engine, Request
+from repro.serving.gateway import ClusterGateway
 from repro.serving.telemetry import (NodeDeathEvent, Telemetry,
                                      tail_percentile)
 
@@ -92,3 +106,162 @@ def test_recovery_time_from_death_events():
     t2.node_death(NodeDeathEvent(node_id=1, t=1.0, cause="test",
                                  requeued_stages=(7,)))
     assert t2.summary("x", [], {}, 10.0, 2.0).recovery_time_s == 0.0
+
+
+# ------------------------------------------------------------------ spans
+
+
+class _SpanLog:
+    """A recording annotation factory: every span with its args (those
+    given at entry and through ``set_metadata``) and its enclosing span."""
+
+    def __init__(self):
+        self.spans, self._stack = [], []
+
+    def __call__(self, name, **args):
+        log = self
+
+        class _Span:
+            def __enter__(self):
+                self.rec = {"name": name, "args": dict(args),
+                            "parent": log._stack[-1] if log._stack else None}
+                log.spans.append(self.rec)
+                log._stack.append(self.rec)
+                return self
+
+            def __exit__(self, *exc):
+                log._stack.pop()
+                return False
+
+            def set_metadata(self, **more):
+                self.rec["args"].update(more)
+
+        return _Span()
+
+
+@pytest.fixture
+def spans_reset():
+    yield
+    telemetry.enable_spans(None)
+
+
+@pytest.fixture(scope="module")
+def tiny_model():
+    cfg = get_config("qwen3-8b").reduced()
+    m = build_model(cfg)
+    return cfg, m, m.init(jax.random.PRNGKey(0))
+
+
+def _engine_run(tiny_model, lengths, *, chunk=16, horizon=4, max_new=9,
+                sequential=False):
+    cfg, m, params = tiny_model
+    eng = Engine(m, params, MemoryAccountant(m_total=512e6), max_slots=3,
+                 s_max=64, kv_backend="ref", prefill_chunk_tokens=chunk,
+                 decode_horizon=horizon)
+    rng = np.random.default_rng(0)
+    reqs = [Request(req_id=i, tokens=[int(t) for t in
+                                      rng.integers(0, cfg.vocab, n)],
+                    max_new=max_new) for i, n in enumerate(lengths)]
+    if sequential:
+        for r in reqs:
+            eng.submit(r)
+            eng.drain()
+    else:
+        for r in reqs:
+            eng.submit(r)
+        eng.drain()
+    return eng
+
+
+def test_spans_off_create_no_annotation(tiny_model, spans_reset):
+    calls = []
+
+    def counting(name, **args):
+        calls.append(name)
+        return telemetry.NULL_SPAN
+
+    telemetry.enable_spans(counting)
+    telemetry.enable_spans(None)
+    assert telemetry.span("engine.step") is telemetry.NULL_SPAN
+    assert telemetry.span("node.submit", node=0, req=3) is telemetry.NULL_SPAN
+    eng = _engine_run(tiny_model, (5, 20, 40))
+    assert eng.stat_chunk_launches > 0 and eng.stat_horizon_steps > 0
+    assert calls == []
+    # the same run with the factory on passes through it: the check above
+    # is not vacuous
+    telemetry.enable_spans(counting)
+    _engine_run(tiny_model, (5, 20, 40))
+    assert {"maestro:engine.chunk", "maestro:engine.horizon.sync"} \
+        <= set(calls)
+
+
+@pytest.mark.parametrize("sequential,launches", [(False, 3), (True, 6)])
+def test_chunk_counters_match_hand_counts(tiny_model, sequential, launches):
+    # 16-token chunks: 5 -> 1 chunk, 20 -> 2, 40 -> 3. Together, each
+    # launch advances every prompt still prefilling (3 launches); one at a
+    # time, 1 + 2 + 3
+    eng = _engine_run(tiny_model, (5, 20, 40), sequential=sequential)
+    assert eng.stat_chunk_launches == launches
+    assert eng.stat_chunk_tokens == 5 + 20 + 40
+
+
+def _ancestors(rec):
+    out = []
+    while rec["parent"] is not None:
+        rec = rec["parent"]
+        out.append(rec["name"])
+    return out
+
+
+def test_gateway_step_spans_nest_to_the_chunk_sync(spans_reset):
+    zoo, host = build_zoo(("qwen3-8b",), seed=1)
+    node, = build_fleet(ClusterSpec(
+        nodes=(NodeSpec(0, max_slots=2, prefix_cache=True,
+                        prefill_chunk_tokens=16, decode_horizon=4),),
+        rtt_s=np.array([[0.001]]), model_names=("qwen3-8b",)),
+        zoo=zoo, host=host)
+    obs = StageObservation(app=0, role=0, position=0.0, invocation_idx=0,
+                           tools_available=0, cot=False, prompt_len=40,
+                           model_id=0, text="span test", src_cluster=0)
+    prompt = [int(t) for t in np.arange(40) % 97 + 1]
+    job = LiveJob(job_id=0, app="t", interactive=True, arrival_s=0.0,
+                  stages=[LiveStage(stage_id=s, job_id=0, deps=deps,
+                                    obs=obs, interactive=True,
+                                    tokens=prompt + [s] * 8, max_new=6)
+                          for s, deps in ((0, []), (1, [0]))])
+    gw = ClusterGateway([node], np.array([[0.001]]),
+                        predictor=StubPred(6.0), policy="maestro")
+    log = _SpanLog()
+    telemetry.enable_spans(log)
+    gw.submit_jobs([job])
+    for _ in range(200):
+        gw.step()
+        if len(gw.done) == 2:
+            break
+    assert len(gw.done) == 2
+    telemetry.enable_spans(None)
+
+    syncs = [s for s in log.spans
+             if s["name"] == "maestro:engine.chunk.sync"]
+    assert syncs
+    for sync in syncs:
+        assert _ancestors(sync) == [
+            "maestro:engine.chunk", "maestro:engine.step",
+            "maestro:node.step", "maestro:gateway.collect",
+            "maestro:gateway.step"]
+    # every span that acts on one request names it; a stage's spans share
+    # the gateway's stage id, from submit through lookup and publish to
+    # finish
+    by_req = collections.defaultdict(set)
+    for s in log.spans:
+        if "req" in s["args"]:
+            by_req[s["args"]["req"]].add(s["name"])
+    for sid in (0, 1):
+        assert {"maestro:node.submit", "maestro:engine.prefix_lookup",
+                "maestro:engine.publish", "maestro:gateway.finish"} \
+            <= by_req[sid]
+    assert set(by_req) == {0, 1}
+    chunks = [s for s in log.spans if s["name"] == "maestro:engine.chunk"]
+    assert sum(s["args"]["tokens"] for s in chunks) \
+        == node.kv_stats()["engine_chunk_tokens"] > 0
+    assert len(chunks) == node.kv_stats()["engine_chunk_launches"]
