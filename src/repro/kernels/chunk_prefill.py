@@ -4,10 +4,10 @@ fused iteration loop (continuous batching with chunked prefill).
 A fixed-width chunk of C prompt tokens per sequence attends to everything
 already written to its arena pages — earlier chunks of the same prompt and
 the current chunk's own K/V, which the caller scatters into the pages before
-attending — under a causal mask on absolute token positions. The fixed
-[B, C] query shape is the whole point: every chunk of every prompt reuses
-one compiled executable, killing the per-prompt-length recompiles of
-monolithic prefill.
+attending — under a causal mask on absolute token positions. C is fixed and
+B is the launch width, one of the few the engine compiles before first use:
+no prompt length adds an executable, which kills the per-prompt-length
+recompiles of monolithic prefill.
 
 Grid (batch, page_slots); the page-slot dimension is innermost/sequential so
 online-softmax state persists in VMEM scratch, exactly like

@@ -315,10 +315,11 @@ class Model:
         write coordinates of the chunk tokens; last_idx [B] the in-chunk
         index of each sequence's last real token (its logit row — only
         meaningful for the chunk that completes a prompt). ``attend`` is the
-        chunked-prefill attention bound once at engine construction. The
-        fixed [B,C] shape is the recompile killer: every chunk of every
-        prompt length reuses one traced executable. Returns (last-token
-        logits [B,Vp] f32, k_pages, v_pages) — pages are donatable.
+        chunked-prefill attention bound once at engine construction. C is
+        fixed and B is the launch width, one of the few the engine compiles
+        before first use, so no prompt length adds an executable. Returns
+        (last-token logits [B,Vp] f32, k_pages, v_pages) — pages are
+        donatable.
         """
         assert self.supports_chunked_prefill, self.cfg.name
         cfg = self.cfg

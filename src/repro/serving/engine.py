@@ -8,12 +8,17 @@ decode sequence contributes its single next-token position, and sequences
 still prefilling contribute one fixed-width chunk of ``prefill_chunk_tokens``
 prompt tokens each, streamed into the arena page-by-page through
 ``Model.prefill_chunk``. Prompts therefore never stall decode slots, slots
-join and leave at iteration granularity, and the fixed chunk shape means one
-traced executable serves every prompt length (no per-length recompiles).
+join and leave at iteration granularity, and a chunk launch packs only the
+prompts that advance into its lanes, at the smallest of a bounded set of
+widths (:func:`chunk_buckets`): every width is compiled before the engine's
+first chunk launch needs it, and no prompt length adds a program.
 With ``prefill_chunk_tokens=0`` (the default) admission falls back to the
 original monolithic one-shot prefill, bit-identical to earlier revisions.
 Preemption is boundary-only: requests are only evicted between engine steps,
-with their KV accounted and reclaimable.
+with their KV accounted and reclaimable. A decoding lane that outgrows its
+admission grant takes pages from the prefix index's pins, then waits, and
+stalls the engine only until its most recent lane is preempted; it finishes
+truncated only where nothing on the engine can make room for it.
 
 KV layout: self-attention K/V lives in the node's PHYSICAL paged arena
 (:mod:`repro.serving.kv_arena`) — every pool page grant maps to one arena
@@ -46,6 +51,16 @@ from repro.kernels import ref as _ref
 from repro.models.transformer import Model
 from repro.serving.kv_arena import KVArena
 from repro.serving.telemetry import span
+
+
+def chunk_buckets(max_slots: int) -> List[int]:
+    """The widths a chunk launch may take: the powers of two below
+    ``max_slots``, then ``max_slots`` itself."""
+    out, b = [], 1
+    while b < max_slots:
+        out.append(b)
+        b *= 2
+    return out + [max_slots]
 
 
 def _model_jit(model: "Model", key: tuple, builder):
@@ -249,6 +264,11 @@ class Engine:
             self._chunk_fwd = _model_jit(
                 model, ("chunk_tok", kv_backend, self.page_tokens),
                 lambda: jax.jit(_chunk_tok, donate_argnums=(1, 2)))
+            # launch signatures whose program has run once, shared by every
+            # engine of the model (jit keeps its executables per model)
+            self._chunk_ready = _model_jit(
+                model, ("chunk_ready", kv_backend, self.page_tokens), set)
+            self._chunk_buckets = chunk_buckets(max_slots)
         self._prefill_pos: Dict[int, int] = {}   # rid -> prompt tokens done
         # stubbed modality frontends (§IV prototype): encoder-decoder and
         # cross-attention models prefill against precomputed frame / patch
@@ -272,10 +292,17 @@ class Engine:
         # OR per horizon launch) — host_syncs_per_token = syncs / tokens
         self.stat_horizon_steps = 0
         self.stat_decode_syncs = 0
-        # chunk programs launched and the real prompt tokens they ran (the
-        # rest of each [max_slots, chunk_tokens] launch is padding)
+        # chunk programs launched, the lanes they launched (each launch's
+        # bucket width) and the real prompt tokens they ran (the rest of
+        # each [lanes, chunk_tokens] launch is padding)
         self.stat_chunk_launches = 0
+        self.stat_chunk_lanes = 0
         self.stat_chunk_tokens = 0
+        # decode page growth under pressure: prefix pins trimmed for it,
+        # lane-steps that waited for a page, lanes preempted to make room
+        self.stat_pin_trims = 0
+        self.stat_grow_waits = 0
+        self.stat_preemptions = 0
         self.finished: List[Request] = []
 
     # -------------------------------------------------------------- state
@@ -544,64 +571,90 @@ class Engine:
                                 n_prefix_tokens=(i + 1) * page)
                 parent = d
 
+    def _chunk_args(self, width: int):
+        """Arguments of a [width, C] chunk launch with every lane padding:
+        tokens and positions 0, writes to the plane's null row."""
+        C = self.chunk_tokens
+        lanes = np.zeros((width, C), np.int32)
+        return (lanes, lanes.copy(),
+                np.zeros((width, self.binding.bt_width), np.int32),
+                lanes.copy(), lanes.copy(), np.zeros(width, np.int32))
+
+    def _launch_chunk(self, toks, pos, bt, rows, offs, last_idx):
+        plane = self.binding.plane
+        tok_dev, logits, plane.k, plane.v = self._chunk_fwd(
+            self.params, plane.k, plane.v, jnp.asarray(toks),
+            jnp.asarray(pos), jnp.asarray(bt), jnp.asarray(rows),
+            jnp.asarray(offs), jnp.asarray(last_idx))
+        return tok_dev, logits
+
+    def _ready_chunk_buckets(self) -> None:
+        """Make every bucket's chunk program ready before a launch needs it:
+        a width not yet run over this plane's shape on this device runs
+        once with every lane padding, so it writes only the null row. Once
+        per (model, device, plane shape) across the model's engines; a
+        plane that grows has a new shape, and so new programs."""
+        plane = self.binding.plane
+        sig = (self.chunk_tokens, self.binding.bt_width, plane.k.shape,
+               plane.k.dtype, next(iter(plane.k.devices())))
+        for width in self._chunk_buckets:
+            self._note_prefill_shape(("chunk", width, self.chunk_tokens))
+            if (width,) + sig not in self._chunk_ready:
+                self._launch_chunk(*self._chunk_args(width))
+                self._chunk_ready.add((width,) + sig)
+
     def _prefill_chunk_batch(self, rids: List[int]) -> None:
         """One fused chunk forward for the given mid-prefill sequences: each
-        contributes the next ``chunk_tokens`` of its prompt at fixed shape
-        [max_slots, C]. Slots not advancing this iteration (idle, decoding,
-        or budget-deferred) are padding — their tokens/positions are zero and
-        their write coordinates point at the plane's null row, so the forward
-        is shape-stable and their garbage rows are discarded. A sequence
-        whose chunk reaches the end of its prompt gets its first output
-        token from that chunk's last-row logits and joins decode at the NEXT
-        iteration (join-at-iteration-granularity)."""
-        with span("engine.chunk", lanes=len(rids)) as sp:
+        contributes the next ``chunk_tokens`` of its prompt in lane ``i`` of
+        a [width, C] launch, width the smallest bucket
+        (:func:`chunk_buckets`) that holds them all. Lanes past the
+        sequences are padding — their tokens/positions are zero and their
+        write coordinates point at the plane's null row — and every
+        bucket's program is ready before the first launch, so no width
+        compiles while serving. A sequence whose chunk reaches the end of
+        its prompt gets its first output token from that chunk's last-row
+        logits and joins decode at the NEXT iteration
+        (join-at-iteration-granularity)."""
+        width = next(b for b in self._chunk_buckets if b >= len(rids))
+        with span("engine.chunk", lanes=len(rids), width=width) as sp:
             self._ensure_cache()
+            self._ready_chunk_buckets()
             C = self.chunk_tokens
             page = self.page_tokens
-            toks = np.zeros((self.max_slots, C), np.int32)
-            pos = np.zeros((self.max_slots, C), np.int32)
-            rows = np.zeros((self.max_slots, C), np.int32)
-            offs = np.zeros((self.max_slots, C), np.int32)
-            bt = np.zeros((self.max_slots, self.binding.bt_width), np.int32)
-            last_idx = np.zeros(self.max_slots, np.int32)
+            toks, pos, bt, rows, offs, last_idx = self._chunk_args(width)
             ran = 0
-            for rid in rids:
+            for lane, rid in enumerate(rids):
                 req = self.active[rid]
-                slot = self.slot_of[rid]
                 p0 = self._prefill_pos[rid]
                 n = min(C, len(req.tokens) - p0)
                 table = self.binding.row_table(rid)
-                bt[slot] = table
+                bt[lane] = table
                 abs_t = np.arange(p0, p0 + n)
-                toks[slot, :n] = req.tokens[p0:p0 + n]
-                pos[slot, :n] = abs_t
-                rows[slot, :n] = table[abs_t // page]
-                offs[slot, :n] = abs_t % page
-                last_idx[slot] = n - 1
+                toks[lane, :n] = req.tokens[p0:p0 + n]
+                pos[lane, :n] = abs_t
+                rows[lane, :n] = table[abs_t // page]
+                offs[lane, :n] = abs_t % page
+                last_idx[lane] = n - 1
                 self._prefill_pos[rid] = p0 + n
                 ran += n
             sp.set_metadata(tokens=ran)
             self.stat_prefill_tokens += ran
             self.stat_chunk_tokens += ran
+            self.stat_chunk_lanes += width
             self.stat_chunk_launches += 1
-            self._note_prefill_shape(("chunk", C))
             self._tables_dirty = True
-            plane = self.binding.plane
-            tok_dev, logits, plane.k, plane.v = self._chunk_fwd(
-                self.params, plane.k, plane.v, jnp.asarray(toks),
-                jnp.asarray(pos), jnp.asarray(bt), jnp.asarray(rows),
-                jnp.asarray(offs), jnp.asarray(last_idx))
+            tok_dev, logits = self._launch_chunk(toks, pos, bt, rows, offs,
+                                                 last_idx)
             with span("engine.chunk.sync"):
                 nxt = np.asarray(tok_dev)
-        for rid in rids:
+        for lane, rid in enumerate(rids):
             req = self.active[rid]
             if self._prefill_pos[rid] < len(req.tokens):
                 continue                       # more chunks to stream
             del self._prefill_pos[rid]
-            slot = self.slot_of[rid]
-            self.positions[slot] = len(req.tokens)
-            self._keep_logits(req, logits, slot)
-            self._first_token(req, int(nxt[slot]))
+            self.positions[self.slot_of[rid]] = len(req.tokens)
+            self._keep_logits(req, logits, lane)
+            self._first_token(req, int(nxt[lane]))
             if self._pc is not None:
                 hit = self._hits.pop(rid, None)
                 self._index_prompt(req, hit.digests if hit is not None
@@ -656,28 +709,63 @@ class Engine:
 
     def _grow_pages(self, decode_rids: List[int], use_horizon: bool,
                     caps: Dict[int, int]) -> None:
-        """Grow page coverage for this step's token writes; a sequence the
-        pool cannot extend finishes truncated (honest backpressure instead
-        of silent overflow) and leaves ``decode_rids``."""
+        """Grow page coverage for this step's token writes; a lane that gets
+        none leaves ``decode_rids`` for the step. Under pressure, in order:
+        the prefix index gives up its least recently used pins; a lane still
+        refused waits, holding its slot and pages; if then no lane advances
+        and no chunk runs, the most recently admitted lane is preempted to
+        the front of ``waiting`` (its stage recomputes) and the others try
+        again at once, before its re-admission can take the pages back."""
+        blocked = self._grant_pages(decode_rids, use_horizon, caps)
+        if blocked and not decode_rids and not self._prefill_pos:
+            victim = next(reversed(self.active))    # admitted most recently
+            blocked.remove(victim)
+            self.waiting.appendleft(self.evict(victim))
+            self.stat_preemptions += 1
+            decode_rids.extend(blocked)
+            blocked = self._grant_pages(decode_rids, use_horizon, caps)
+        self.stat_grow_waits += len(blocked)
+
+    def _grant_pages(self, decode_rids: List[int], use_horizon: bool,
+                     caps: Dict[int, int]) -> List[int]:
+        """Grant each lane of ``decode_rids`` its step's pages, trimming
+        prefix pins while it is refused; returns the lanes still refused,
+        which leave ``decode_rids``. A refused lane that nothing on this
+        engine can make room for finishes truncated instead (honest
+        backpressure, never silent overflow): one alone on the engine, or
+        one the pool refused with the accountant's headroom for a page
+        intact, whose refusal no yield of memory cures."""
+        blocked = []
         for rid in list(decode_rids):
-            pos = int(self.positions[self.slot_of[rid]])
-            if use_horizon:
-                # pre-grant up to a horizon's worth of pages; a partial
-                # grant caps that lane's emission budget (it stays active
-                # and retries next step), a zero grant truncates exactly
-                # like the one-token path
-                req = self.active[rid]
-                want = min(self.horizon, req.max_new - len(req.out),
-                           self.s_max - 1 - pos)
-                got = self._pregrant(rid, pos, want)
-                if got > 0:
-                    caps[rid] = got
-                    continue
-            elif self.binding.ensure_tokens(rid, pos + 1):
+            got = self._grant(rid, use_horizon)
+            while got == 0 and self._pc is not None and self._pc.trim(1):
+                self.stat_pin_trims += 1
+                got = self._grant(rid, use_horizon)
+            if got > 0:
+                caps[rid] = got
                 continue
-            self.active[rid].truncated = True
-            self._release(rid)
             decode_rids.remove(rid)
+            if got < 0 or len(self.active) == 1 or self.acc.can_admit(
+                    self.pool.page_bytes):
+                self.active[rid].truncated = True
+                self._release(rid)
+            else:
+                blocked.append(rid)
+        return blocked
+
+    def _grant(self, rid: int, use_horizon: bool) -> int:
+        """Pages for one lane's writes this step; returns the tokens it may
+        emit (0: refused). The horizon pre-grants up to a horizon's worth,
+        and a partial grant caps that lane's emission budget; a lane whose
+        first token already ended its stage wants none (-1), and finishes
+        flagged truncated."""
+        pos = int(self.positions[self.slot_of[rid]])
+        if use_horizon:
+            req = self.active[rid]
+            want = min(self.horizon, req.max_new - len(req.out),
+                       self.s_max - 1 - pos)
+            return self._pregrant(rid, pos, want) if want > 0 else -1
+        return int(self.binding.ensure_tokens(rid, pos + 1))
 
     def _decode_one(self, decode_rids: List[int]) -> None:
         """One-token decode of every decoding lane, launch to argmax sync."""
@@ -720,8 +808,8 @@ class Engine:
     def _pregrant(self, rid: int, pos: int, want: int) -> int:
         """Pre-grant pages for up to ``want`` horizon writes starting at
         ``pos``. Returns the emission budget actually covered (0 = not even
-        one write grantable -> caller truncates, the same backpressure as
-        the one-token path). Grants are page-granular: when the pool
+        one write grantable -> the same backpressure as the one-token
+        path). Grants are page-granular: when the pool
         refuses the full horizon the budget falls back page by page, down
         to whatever the current grant already covers."""
         page = self.page_tokens

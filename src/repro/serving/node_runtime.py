@@ -313,12 +313,23 @@ class NodeRuntime:
                    e.stat_horizon_steps for e in self.engines.values()),
                "engine_decode_syncs": sum(
                    e.stat_decode_syncs for e in self.engines.values()),
-               # chunked-prefill fill: launches and the real prompt tokens
-               # they ran, of max_slots x prefill_chunk_tokens each
+               # chunked-prefill fill: launches, the lanes they launched
+               # (bucket widths) and the real prompt tokens they ran, of
+               # lanes x prefill_chunk_tokens
                "engine_chunk_launches": sum(
                    e.stat_chunk_launches for e in self.engines.values()),
+               "engine_chunk_lanes": sum(
+                   e.stat_chunk_lanes for e in self.engines.values()),
                "engine_chunk_tokens": sum(
-                   e.stat_chunk_tokens for e in self.engines.values())}
+                   e.stat_chunk_tokens for e in self.engines.values()),
+               # decode page growth under pressure: prefix pins trimmed,
+               # lane-steps that waited, lanes preempted
+               "engine_pin_trims": sum(
+                   e.stat_pin_trims for e in self.engines.values()),
+               "engine_grow_waits": sum(
+                   e.stat_grow_waits for e in self.engines.values()),
+               "engine_preemptions": sum(
+                   e.stat_preemptions for e in self.engines.values())}
         if self.arena.prefix_index is not None:
             out.update(self.arena.prefix_index.stats())
         return out

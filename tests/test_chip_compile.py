@@ -70,12 +70,14 @@ def test_paged_attention_compiles(one_chip, inline):
              **(dict(k_new=new, v_new=new) if inline else {}))
 
 
-def test_chunk_prefill_attention_compiles(one_chip):
+@pytest.mark.parametrize("lanes", [1, 2, 4, 8, 16])
+def test_chunk_prefill_attention_compiles(one_chip, lanes):
+    """Every launch width the engine's chunk buckets take on 16 slots."""
     s = functools.partial(_spec, one_chip)
     pages = s((ROWS, PAGE, HKV, HD), jnp.bfloat16)
     _compile(functools.partial(chunk_prefill_attention, page_size=PAGE),
-             s((B, CHUNK, H, HD), jnp.bfloat16), pages, pages,
-             s((B, WIDTH), jnp.int32), s((B, CHUNK), jnp.int32))
+             s((lanes, CHUNK, H, HD), jnp.bfloat16), pages, pages,
+             s((lanes, WIDTH), jnp.int32), s((lanes, CHUNK), jnp.int32))
 
 
 def test_decode_step_paged_compiles(one_chip):

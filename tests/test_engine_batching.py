@@ -6,6 +6,10 @@ and off, every zoo model with self-attention KV). Logits are allowed to
 drift at ulp level — fixed-shape padded reductions reassociate differently
 than per-length monolithic prefill — which greedy argmax absorbs.
 
+Chunk launches are lane-compacted: a launch packs the prompts that advance
+into its first lanes and is as wide as the smallest bucket that holds them
+(:func:`chunk_buckets`), every bucket compiled before the first launch.
+
 Also pins the satellite contracts of the same PR: deque-backed waiting
 queue with preserved requeue semantics, ``EngineStalledError`` from an
 exhausted drain, ``step()`` returning only newly-finished requests, and the
@@ -18,7 +22,10 @@ import pytest
 from repro.configs import get_config
 from repro.core.runtime.accounting import MemoryAccountant
 from repro.models import build_model
-from repro.serving.engine import Engine, EngineStalledError, Request
+from repro.serving import telemetry
+from repro.serving.engine import (Engine, EngineStalledError, Request,
+                                  chunk_buckets)
+from repro.serving.kv_arena import KVArena
 
 CHUNK_ZOO = ("qwen3-8b", "starcoder2-15b")     # self-attention KV models
 
@@ -120,7 +127,8 @@ def test_prefill_compile_count_flat_across_prompt_lengths(tiny):
     mono_eng, _ = _drain_all(m, params, prompts, chunk=0)
     chk_eng, _ = _drain_all(m, params, prompts, chunk=4)
     assert mono_eng.prefill_compiles == 6          # one trace per length
-    assert chk_eng.prefill_compiles == 1           # one fixed chunk shape
+    # one chunk program per launch width, whatever the prompt lengths
+    assert chk_eng.prefill_compiles == len(chunk_buckets(3)) == 3
     # counters flow through the node snapshot for gateway aggregation
     total = sum(len(p) for p in prompts)
     assert chk_eng.stat_prefill_tokens == total
@@ -144,7 +152,7 @@ def test_engine_counters_exposed_in_node_kv_stats(tiny):
     st = node.kv_stats()
     assert st["engine_prefill_tokens"] == 5
     assert st["engine_decode_tokens"] > 0
-    assert st["engine_prefill_compiles"] == 1
+    assert st["engine_prefill_compiles"] == len(chunk_buckets(2)) == 2
     assert st["engine_steps"] > 0
 
 
@@ -154,6 +162,131 @@ def test_ttft_stamped_on_finished_requests(tiny):
     assert all(r.ttft_s > 0 for r in done.values())
     _, done = _drain_all(m, params, _prompts(cfg)[:3], chunk=0)
     assert all(r.ttft_s > 0 for r in done.values())
+
+
+# ------------------------------------------------------- lane compaction
+@pytest.mark.parametrize("max_slots,buckets", [
+    (1, [1]), (2, [1, 2]), (3, [1, 2, 3]), (6, [1, 2, 4, 6]),
+    (16, [1, 2, 4, 8, 16])])
+def test_chunk_buckets_are_powers_of_two_then_max_slots(max_slots, buckets):
+    assert chunk_buckets(max_slots) == buckets
+
+
+class _ChunkSpans:
+    """An annotation factory that keeps the args of every ``engine.chunk``
+    span as it opens."""
+
+    def __init__(self):
+        self.args = []
+
+    def __call__(self, name, **args):
+        if name == "maestro:engine.chunk":
+            self.args.append(args)
+        return telemetry.NULL_SPAN
+
+
+@pytest.mark.parametrize("lanes", [1, 2, 3, 5, 6])
+def test_compacted_chunk_launch_matches_fixed_width(tiny, lanes):
+    """``lanes`` prompts of different lengths prefill together on 6 slots
+    (buckets 1, 2, 4, 6): each launch is the smallest bucket that holds the
+    prompts it advances, outputs are those of the fixed [max_slots, C]
+    launch, and so are kept first-token logits, to a few float32 ulps (the
+    CPU backend's matmul blocks its rows by the launch's height)."""
+    cfg, m, params = tiny
+    rng = np.random.default_rng(17)
+    prompts = [list(rng.integers(0, cfg.vocab, 5 + 7 * i))
+               for i in range(lanes)]
+
+    def serve(fixed):
+        eng = Engine(m, params, MemoryAccountant(m_total=512e6),
+                     max_slots=6, s_max=64, kv_backend="ref",
+                     prefill_chunk_tokens=8)
+        if fixed:
+            eng._chunk_buckets = [eng.max_slots]
+        for i, p in enumerate(prompts):
+            eng.submit(Request(req_id=i, tokens=list(p), max_new=5,
+                               logits=[]))
+        return eng, {r.req_id: r for r in eng.drain()}
+
+    _, ref = serve(fixed=True)
+    log = _ChunkSpans()
+    telemetry.enable_spans(log)
+    try:
+        eng, got = serve(fixed=False)
+    finally:
+        telemetry.enable_spans(None)
+    assert {k: r.out for k, r in got.items()} == \
+        {k: r.out for k, r in ref.items()}
+    for k, r in got.items():
+        want = np.asarray(ref[k].logits[0])
+        np.testing.assert_allclose(
+            np.asarray(r.logits[0]), want, rtol=0,
+            atol=64 * np.finfo(np.float32).eps * np.abs(want).max())
+    assert max(a["lanes"] for a in log.args) == lanes
+    for a in log.args:
+        assert a["width"] == min(b for b in (1, 2, 4, 6) if b >= a["lanes"])
+    assert eng.stat_chunk_launches == len(log.args)
+    assert eng.stat_chunk_lanes == sum(a["width"] for a in log.args)
+    assert eng.arena.check_mirror()
+
+
+def test_padding_lanes_write_only_the_null_row(tiny):
+    """Three prompts advance in a 4-lane launch on 6 slots (and the first
+    launch readies every bucket on lanes that are all padding): every row
+    that no sequence holds keeps its bytes."""
+    cfg, m, params = tiny
+    rng = np.random.default_rng(19)
+    eng = Engine(m, params, MemoryAccountant(m_total=512e6), max_slots=6,
+                 s_max=64, kv_backend="ref", prefill_chunk_tokens=8,
+                 arena=KVArena(16, init_rows=64))
+    plane = eng.binding.plane
+    noise = jax.random.normal(jax.random.PRNGKey(1), plane.k.shape)
+    plane.k = noise.astype(plane.k.dtype)
+    plane.v = (-noise).astype(plane.v.dtype)
+    k0, v0 = np.asarray(plane.k), np.asarray(plane.v)
+    for i in range(3):
+        eng.submit(Request(req_id=i, max_new=4,
+                           tokens=list(rng.integers(0, cfg.vocab, 12 + i))))
+    eng.step()                      # admission and one chunk launch
+    assert eng.stat_chunk_lanes == 4 and eng.stat_chunk_tokens == 3 * 8
+    held = {r for rid in eng.active for r in eng.binding.seq_rows(rid)}
+    free = [r for r in range(1, plane.n_rows) if r not in held]
+    np.testing.assert_array_equal(np.asarray(plane.k)[:, free], k0[:, free])
+    np.testing.assert_array_equal(np.asarray(plane.v)[:, free], v0[:, free])
+    assert eng.arena.check_mirror()
+
+
+def test_no_compile_after_first_chunk_launch(tiny):
+    """The first chunk launch readies every bucket: serving prompts that
+    advance 2 to 6 together afterwards compiles and loads nothing (a plane
+    that never grows)."""
+    cfg, m, params = tiny
+    rng = np.random.default_rng(13)
+    eng = Engine(m, params, MemoryAccountant(m_total=512e6), max_slots=6,
+                 s_max=64, kv_backend="ref", prefill_chunk_tokens=4,
+                 arena=KVArena(16, init_rows=128))
+    eng.submit(Request(req_id=0, tokens=list(rng.integers(0, cfg.vocab, 9)),
+                       max_new=4))
+    eng.drain()                     # one lane: launches width 1 only
+    seen = []
+
+    def count(event, secs, **kw):
+        if event in ("/jax/core/compile/backend_compile_duration",
+                     "/jax/compilation_cache/cache_retrieval_time_sec"):
+            seen.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(count)
+    try:
+        for lanes in (2, 3, 6):
+            for i in range(lanes):
+                eng.submit(Request(
+                    req_id=10 * lanes + i, max_new=4,
+                    tokens=list(rng.integers(0, cfg.vocab, 6 + 5 * i))))
+            eng.drain()
+    finally:
+        jax.monitoring.unregister_event_duration_listener(count)
+    assert eng.stat_chunk_lanes > eng.stat_chunk_launches   # wider launches
+    assert seen == []
 
 
 # --------------------------------------------------------- token budget

@@ -1,5 +1,6 @@
-"""Percentile edge cases + fault/tail columns in the telemetry plane, and
-the program's spans and chunk counters.
+"""Percentile edge cases + fault/tail columns in the telemetry plane, the
+program's spans and chunk counters, and the engine's decode page growth
+under pressure with its counters.
 
 The fleet-summed benchmark paths serialize ``GatewayMetrics.row()`` straight
 into BENCH JSON: a NaN (np.percentile of an empty array) or an IndexError
@@ -265,3 +266,108 @@ def test_gateway_step_spans_nest_to_the_chunk_sync(spans_reset):
     assert sum(s["args"]["tokens"] for s in chunks) \
         == node.kv_stats()["engine_chunk_tokens"] > 0
     assert len(chunks) == node.kv_stats()["engine_chunk_launches"]
+
+
+# ------------------------------------------- decode page growth under pressure
+
+
+def _squeezed(tiny_model, reqs, *, squeeze, horizon=1, prefix_cache=False,
+              cached=()):
+    """Serve ``reqs`` (prompt, max_new, pred_len) on 3 slots, in chunks of
+    16. ``cached`` prompts are served first, so that the prefix index holds
+    their pages and nothing else does. With ``squeeze``, after the step
+    that admits ``reqs`` and runs their first chunk (before any decodes)
+    the accountant has no headroom left: a lane that outgrows its
+    admission grant gets a page only from memory something else gives up."""
+    cfg, m, params = tiny_model
+    acc = MemoryAccountant(m_total=512e6)
+    eng = Engine(m, params, acc, max_slots=3, s_max=64, kv_backend="ref",
+                 prefix_cache=prefix_cache, prefill_chunk_tokens=16,
+                 decode_horizon=horizon)
+    for i, p in enumerate(cached):
+        eng.submit(Request(req_id=100 + i, tokens=list(p), max_new=1))
+    eng.drain()
+    for i, (p, n, pred) in enumerate(reqs):
+        eng.submit(Request(req_id=i, tokens=list(p), max_new=n,
+                           pred_len=pred))
+    done = {r.req_id: r for r in eng.step()}
+    assert not eng.waiting
+    if squeeze:
+        acc.m_other += acc.headroom
+    done.update({r.req_id: r for r in eng.drain()})
+    assert eng.arena.check_mirror()
+    return eng, done
+
+
+def _tokens(n, seed):
+    return [int(t) for t in np.random.default_rng(seed).integers(1, 97, n)]
+
+
+@pytest.mark.parametrize("horizon", [1, 8])
+def test_decode_growth_trims_pins_before_any_lane_waits(tiny_model,
+                                                        horizon):
+    """The prefix index holds 12 pages no sequence uses and the accountant
+    nothing more: each lane that outgrows its grant takes a pinned page,
+    so none waits and none is truncated."""
+    reqs = [(_tokens(20, 1), 24, 1), (_tokens(21, 2), 24, 1)]
+    cached = [_tokens(48, 10 + i) for i in range(4)]
+    _, free = _squeezed(tiny_model, reqs, squeeze=False, horizon=horizon)
+    eng, got = _squeezed(tiny_model, reqs, squeeze=True, horizon=horizon,
+                         prefix_cache=True, cached=cached)
+    assert {k: r.out for k, r in got.items()} == \
+        {k: r.out for k, r in free.items()}
+    assert not any(r.truncated for r in got.values())
+    assert eng.stat_pin_trims > 0
+    assert eng.stat_grow_waits == 0 and eng.stat_preemptions == 0
+
+
+@pytest.mark.parametrize("horizon", [1, 8])
+def test_blocked_lane_waits_for_another_to_finish(tiny_model, horizon):
+    """Lane 1 outgrows its grant at token 16 while lane 0, whose grant
+    covers its whole output, still decodes: lane 1 waits until lane 0's
+    pages come back, then ends as it ends unpressured."""
+    reqs = [(_tokens(3, 3), 12, 12), (_tokens(12, 4), 20, 1)]
+    _, free = _squeezed(tiny_model, reqs, squeeze=False, horizon=horizon)
+    eng, got = _squeezed(tiny_model, reqs, squeeze=True, horizon=horizon)
+    assert {k: r.out for k, r in got.items()} == \
+        {k: r.out for k, r in free.items()}
+    assert not any(r.truncated for r in got.values())
+    assert eng.stat_grow_waits > 0 and eng.stat_preemptions == 0
+
+
+@pytest.mark.parametrize("horizon", [1, 8])
+def test_stalled_engine_preempts_its_latest_lane(tiny_model, horizon):
+    """Both lanes outgrow their grants at token 32 together and nothing
+    else can give a page: the lane admitted last is preempted, the other
+    takes its pages at once and finishes, and the preempted stage restarts
+    and ends as it ends unpressured."""
+    reqs = [(_tokens(15, 5), 24, 1), (_tokens(15, 6), 24, 1)]
+    _, free = _squeezed(tiny_model, reqs, squeeze=False, horizon=horizon)
+    eng, got = _squeezed(tiny_model, reqs, squeeze=True, horizon=horizon)
+    assert {k: r.out for k, r in got.items()} == \
+        {k: r.out for k, r in free.items()}
+    assert not any(r.truncated for r in got.values())
+    assert eng.stat_preemptions == 1
+
+
+def test_growth_counters_in_node_kv_stats(tiny_model):
+    from repro.serving.node_runtime import NodeRuntime
+    cfg, m, params = tiny_model
+    host = jax.tree.map(np.asarray, params)
+    node = NodeRuntime(0, 0, {cfg.name: m}, {cfg.name: host},
+                       hbm_budget=1.2e9, max_slots=3, s_max=64,
+                       prefill_chunk_tokens=16)
+    node.submit(cfg.name, Request(req_id=0, tokens=_tokens(20, 7),
+                                  max_new=4))
+    for _ in range(30):
+        node.step()
+        if not node.has_work():
+            break
+    eng = node.engines[cfg.name]
+    eng.stat_pin_trims, eng.stat_grow_waits, eng.stat_preemptions = 3, 2, 1
+    st = node.kv_stats()
+    # two launches of one lane each; the counters are the engines' sums
+    assert st["engine_chunk_launches"] == 2
+    assert st["engine_chunk_lanes"] == 2
+    assert (st["engine_pin_trims"], st["engine_grow_waits"],
+            st["engine_preemptions"]) == (3, 2, 1)
