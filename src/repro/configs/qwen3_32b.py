@@ -1,6 +1,6 @@
 """qwen3-32b — dense, 64L d_model=5120 64H (GQA kv=8) d_ff=25600 vocab=151936.
 
-qk_norm, GQA, head_dim=128. [hf:Qwen/Qwen3-8B; hf]
+qk_norm, GQA, head_dim=128. [hf:Qwen/Qwen3-32B; hf]
 """
 from repro.configs.base import ArchConfig, register
 
@@ -16,5 +16,5 @@ QWEN3_32B = register(ArchConfig(
     vocab=151936,
     qk_norm=True,
     rope_theta=1e6,
-    source="hf:Qwen/Qwen3-8B; hf",
+    source="hf:Qwen/Qwen3-32B; hf",
 ))

@@ -20,10 +20,17 @@ layout (never repeated). Inside, scores are [Hkv, page, C*g]: keys on
 sublanes and query rows on lanes, since a page (16 tokens) is far narrower
 than a 128-lane tile. The output comes back as [B, Hkv, hd, C*g] and is
 regrouped to [B, C, H, hd] outside.
+
+VMEM: the query, output and accumulator blocks grow with R = C*g, and at
+C = 256 with g = 8 (Qwen3-32B) they outgrow Mosaic's default scoped VMEM
+(16 MiB). The kernel then raises its scoped limit to what its own blocks
+take (:func:`vmem_limit_bytes`), within a v5e core's 128 MiB; shapes that
+fit the default (g = 4 at the same chunk) compile with no limit given.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +38,37 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# Mosaic's scoped-VMEM limit when a kernel gives none, and the most this
+# kernel asks for: a v5e TensorCore has 128 MiB of VMEM, and the compiler
+# keeps some of it for itself
+DEFAULT_SCOPED_VMEM = 16 << 20
+MAX_SCOPED_VMEM = 96 << 20
+
+
+def vmem_bytes(R: int, Hkv: int, hd: int, page_size: int,
+               itemsize: int) -> int:
+    """VMEM taken by the kernel's blocks, each double-buffered by the
+    pipeline (the query rows, their positions, one K and one V page, the
+    output), and its f32 scratch (running max, denominator, accumulator)."""
+    rows = Hkv * R * hd
+    blocks = 2 * rows * itemsize + R * 4 + 2 * page_size * Hkv * hd * itemsize
+    return 2 * blocks + 2 * Hkv * R * 4 + rows * 4
+
+
+def vmem_limit_bytes(R: int, Hkv: int, hd: int, page_size: int,
+                     itemsize: int) -> Optional[int]:
+    """The scoped-VMEM limit to compile with: the blocks and scratch and a
+    quarter again for Mosaic's own temporaries (compiling for a v5e at
+    R = 2048 it took 26.2 MiB, 1.9 MiB beyond the blocks), in whole MiB;
+    None, the default, where that fits the default."""
+    want = vmem_bytes(R, Hkv, hd, page_size, itemsize) * 5 // 4
+    if want <= DEFAULT_SCOPED_VMEM:
+        return None
+    limit = -(-want // (1 << 20)) << 20
+    if limit > MAX_SCOPED_VMEM:
+        raise ValueError(f"chunk prefill: {R} query rows of {Hkv} KV heads "
+                         f"want {want} bytes of VMEM, over {MAX_SCOPED_VMEM}")
+    return limit
 
 
 def _chunk_kernel(block_table, k_lens, q_ref, qpos_ref, k_ref, v_ref, o_ref,
@@ -124,9 +162,12 @@ def chunk_prefill_attention(q: jax.Array, k_pages: jax.Array,
             pltpu.VMEM((Hkv, hd, R), jnp.float32),
         ],
     )
+    limit = vmem_limit_bytes(R, Hkv, hd, page_size, q.dtype.itemsize)
     fn = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, hd, R), q.dtype),
+        compiler_params=(None if limit is None else
+                         pltpu.CompilerParams(vmem_limit_bytes=limit)),
         interpret=interpret)
     out = fn(block_table, k_lens, qg, qpos, k_pages, v_pages)
     return (out.reshape(B, Hkv, hd, C, g).transpose(0, 3, 1, 4, 2)

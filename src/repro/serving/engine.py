@@ -114,6 +114,11 @@ class Request:
     # token's f32 logits row [Vp], left on the device — for correctness
     # checks against a reference
     logits: Optional[List[Any]] = None
+    # set by the gateway at placement: whether the stage has a parent stage,
+    # and the node that parent ran on (None where it ran on none), so that
+    # the node counts the children placed beside their parent's transcript
+    has_parent: bool = False
+    parent_node: Optional[int] = None
 
 
 class Engine:

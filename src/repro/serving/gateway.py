@@ -258,6 +258,9 @@ class ClusterGateway:
         self.dropped: set = set()
         self.arrivals: List[Tuple[float, int]] = []   # (arrival_s, job_id)
         self.inflight: Dict[int, _InFlight] = {}      # stage_id -> record
+        # stage_id -> the node of its latest placement: where a child's
+        # parent ran, so its transcript's pages are in that node's index
+        self.placed_on: Dict[int, int] = {}
         self.node_load: Dict[int, int] = {nid: 0 for nid in self.fleet}
         self.inflight_cap: Dict[int, int] = {
             nid: (self.cfg.max_inflight_per_node
@@ -897,31 +900,41 @@ class ClusterGateway:
         view = self.view(stage)
         model = view.model
         rtt = self.rtt(stage, nid)
-        # through the Substrate method, NOT the node: under the wall clock
-        # with free-running workers it answers from the reported signal (a
-        # direct node query would block until an engine-step boundary)
-        t_act = self.t_act(nid, model)
-        if t_act > COLD_START_THRESHOLD_S:
-            self.telemetry.cold_starts += 1
-        l_hat = self.policy.predicted_len(self, view)
-        req = Request(req_id=stage.stage_id, tokens=list(stage.tokens),
-                      max_new=stage.max_new,
-                      pred_len=(None if l_hat is None
-                                else float(min(l_hat, stage.max_new))))
-        rec = _InFlight(
-            stage=stage, node_id=nid, model=model, req=req, r_need=r_need,
-            submit_at=now + rtt + t_act)
-        self.inflight[stage.stage_id] = rec
-        # RTT + activation transit as a timed event release on the clock
-        self.clock.call_at(rec.submit_at, rec)
-        self.node_load[nid] += 1
-        self.pending_resv[nid] += r_need
-        wait = max(0.0, now - self.ready_t.get(stage.stage_id, now))
-        self.qd_ewma[nid] = 0.8 * self.qd_ewma[nid] + 0.2 * (wait + t_act)
-        ev = self.telemetry.event(stage.stage_id, stage.job_id,
-                                  stage.interactive)
-        ev.node_id, ev.dispatch_t = nid, now
-        ev.rtt_s, ev.t_act_s = rtt, t_act
+        # the parent is the first dependency, whose transcript the stage's
+        # prompt carries
+        parent = self.placed_on.get(stage.deps[0]) if stage.deps else None
+        with span("gateway.route", req=stage.stage_id,
+                  src=stage.obs.src_cluster, node=nid, rtt_ms=rtt * 1e3,
+                  affine=parent == nid):
+            # through the Substrate method, NOT the node: under the wall
+            # clock with free-running workers it answers from the reported
+            # signal (a direct node query would block until an engine-step
+            # boundary)
+            t_act = self.t_act(nid, model)
+            if t_act > COLD_START_THRESHOLD_S:
+                self.telemetry.cold_starts += 1
+            l_hat = self.policy.predicted_len(self, view)
+            req = Request(req_id=stage.stage_id, tokens=list(stage.tokens),
+                          max_new=stage.max_new,
+                          pred_len=(None if l_hat is None
+                                    else float(min(l_hat, stage.max_new))),
+                          has_parent=bool(stage.deps), parent_node=parent)
+            self.placed_on[stage.stage_id] = nid
+            rec = _InFlight(
+                stage=stage, node_id=nid, model=model, req=req,
+                r_need=r_need, submit_at=now + rtt + t_act)
+            self.inflight[stage.stage_id] = rec
+            # RTT + activation transit as a timed event release on the clock
+            self.clock.call_at(rec.submit_at, rec)
+            self.node_load[nid] += 1
+            self.pending_resv[nid] += r_need
+            wait = max(0.0, now - self.ready_t.get(stage.stage_id, now))
+            self.qd_ewma[nid] = (0.8 * self.qd_ewma[nid]
+                                 + 0.2 * (wait + t_act))
+            ev = self.telemetry.event(stage.stage_id, stage.job_id,
+                                      stage.interactive)
+            ev.node_id, ev.dispatch_t = nid, now
+            ev.rtt_s, ev.t_act_s = rtt, t_act
 
     def _fire_releases(self, now: float) -> None:
         """Submit every stage whose transit event released. Stale events

@@ -70,6 +70,10 @@ class NodeRuntime:
         self.max_batch_tokens = max_batch_tokens
         self.prefill_chunk_tokens = prefill_chunk_tokens
         self.decode_horizon = decode_horizon
+        # routing: stages submitted here that have a parent stage, and
+        # those of them whose parent ran on this node
+        self.stat_child_stages = 0
+        self.stat_child_affine = 0
         hw = hardware_spec(self.device)
         profiles = {
             name: ModelProfile(
@@ -157,6 +161,9 @@ class NodeRuntime:
     # -------------------------------------------------------------- serving
     def submit(self, model: str, req: Request) -> None:
         with span("node.submit", node=self.node_id, req=req.req_id):
+            if req.has_parent:
+                self.stat_child_stages += 1
+                self.stat_child_affine += req.parent_node == self.node_id
             if model not in self.device_params:
                 self.activate(model)
             self.engines[model].submit(req)
@@ -329,7 +336,11 @@ class NodeRuntime:
                "engine_grow_waits": sum(
                    e.stat_grow_waits for e in self.engines.values()),
                "engine_preemptions": sum(
-                   e.stat_preemptions for e in self.engines.values())}
+                   e.stat_preemptions for e in self.engines.values()),
+               # routing: child stages submitted here, and those placed on
+               # the node their parent ran on
+               "node_child_stages": self.stat_child_stages,
+               "node_child_affine": self.stat_child_affine}
         if self.arena.prefix_index is not None:
             out.update(self.arena.prefix_index.stats())
         return out

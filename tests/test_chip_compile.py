@@ -1,4 +1,5 @@
-"""Ahead-of-time compiles for a described TPU v5e, at qwen3-8b widths.
+"""Ahead-of-time compiles for a described TPU v5e, at qwen3-8b widths (and
+qwen3-32b's attention for the chunked-prefill kernel).
 
 The chip's own compiler is installed with JAX and compiles for a chip that
 is described, not attached: these tests run without one. Interpret-mode
@@ -77,6 +78,18 @@ def test_chunk_prefill_attention_compiles(one_chip, lanes):
     pages = s((ROWS, PAGE, HKV, HD), jnp.bfloat16)
     _compile(functools.partial(chunk_prefill_attention, page_size=PAGE),
              s((lanes, CHUNK, H, HD), jnp.bfloat16), pages, pages,
+             s((lanes, WIDTH), jnp.int32), s((lanes, CHUNK), jnp.int32))
+
+
+@pytest.mark.parametrize("lanes", [1, 8, 32])
+def test_chunk_prefill_attention_compiles_at_group8(one_chip, lanes):
+    """qwen3-32b: 64 query heads over the same 8 KV heads, whose 2048 query
+    rows a chunk need more than the default scoped VMEM; chunk buckets of
+    its 32 slots, ends and middle."""
+    s = functools.partial(_spec, one_chip)
+    pages = s((ROWS, PAGE, HKV, HD), jnp.bfloat16)
+    _compile(functools.partial(chunk_prefill_attention, page_size=PAGE),
+             s((lanes, CHUNK, 64, HD), jnp.bfloat16), pages, pages,
              s((lanes, WIDTH), jnp.int32), s((lanes, CHUNK), jnp.int32))
 
 

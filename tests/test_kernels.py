@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ref
+from repro.kernels import chunk_prefill as cp
 from repro.kernels.chunk_prefill import chunk_prefill_attention
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.paged_attention import paged_attention
@@ -113,6 +114,7 @@ def test_paged_attention_inline_splice_matches_scatter(B, H, Hkv, hd, page,
     (3, 8, 6, 2, 16, 8, 3),      # GQA 3x
     (1, 16, 2, 2, 32, 16, 2),    # MHA, chunk == page
     (2, 8, 8, 1, 64, 4, 6),      # MQA, chunk 2x page
+    (2, 8, 16, 2, 32, 4, 5),     # GQA 8x (qwen3-32b's group)
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_chunk_prefill_attention_sweep(B, C, H, Hkv, hd, page, slots, dtype):
@@ -200,3 +202,25 @@ def test_ops_dispatch_ref_on_cpu():
         ops.set_mode(None)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("group,limit", [
+    (4, None),         # qwen3-8b: its blocks fit Mosaic's default
+    (8, 31 << 20),     # qwen3-32b: twice the rows, its own limit
+])
+def test_chunk_prefill_vmem_limit(group, limit):
+    """A 256-token chunk over 8 KV heads of 128 (bf16, 16-token pages):
+    the kernel keeps one block of all C*g query rows, and asks for more
+    than the default scoped VMEM only where that block needs it, within a
+    v5e core's 128 MiB."""
+    R = 256 * group
+    need = cp.vmem_bytes(R, 8, 128, 16, 2)
+    got = cp.vmem_limit_bytes(R, 8, 128, 16, 2)
+    assert got == limit
+    if got is None:
+        assert need * 5 // 4 <= cp.DEFAULT_SCOPED_VMEM
+    else:
+        assert cp.DEFAULT_SCOPED_VMEM < need < got \
+            <= cp.MAX_SCOPED_VMEM < 128 << 20
+    with pytest.raises(ValueError):       # 1024-token chunks at group 8
+        cp.vmem_limit_bytes(1024 * 8, 8, 128, 16, 2)

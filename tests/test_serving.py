@@ -151,8 +151,29 @@ def test_node_runtime_colocation_and_warm_reactivation():
         node.step()
     node.sleep("qwen3-8b")
     assert "qwen3-8b" not in node.device_params
-    t_warm = node.activate("qwen3-8b")
-    assert t_warm < t_cold            # executable cache survived (Fig. 10)
+    # the executable cache survived (Fig. 10): re-activating the sleeping
+    # model compiles nothing, and serving it again at the same shapes runs
+    # the model's programs compiled before it slept
+    eng = node.engines["qwen3-8b"]
+    programs = (eng._prefill_fwd, eng._decode)
+    sizes = [p._cache_size() for p in programs]
+    compiles = []
+
+    def count(event, secs, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(count)
+    try:
+        t_warm = node.activate("qwen3-8b")
+    finally:
+        jax.monitoring.unregister_event_duration_listener(count)
+    assert compiles == [] and t_warm > 0 and t_cold > 0
+    assert node.engines["qwen3-8b"] is eng
+    node.submit("qwen3-8b", Request(req_id=1, tokens=[6, 7, 8], max_new=4))
+    for _ in range(8):
+        node.step()
+    assert [p._cache_size() for p in programs] == sizes
     sig = node.signal()
     assert sig.headroom > 0
     assert "qwen3-8b" in sig.warm_models

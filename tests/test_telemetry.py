@@ -371,3 +371,85 @@ def test_growth_counters_in_node_kv_stats(tiny_model):
     assert st["engine_chunk_lanes"] == 2
     assert (st["engine_pin_trims"], st["engine_grow_waits"],
             st["engine_preemptions"]) == (3, 2, 1)
+
+
+# ------------------------------------------------------- routing counters
+
+
+def _four_node_run(log=None):
+    """Six three-stage chains from clusters 0-2 through the maestro policy
+    onto four nodes in clusters 0, 0, 1, 2, on the virtual clock; with
+    ``log``, spans on."""
+    from repro.core.topology import DEFAULT_RTT
+    zoo, host = build_zoo(("qwen3-8b",), seed=1)
+    nodes = build_fleet(ClusterSpec(
+        nodes=tuple(NodeSpec(c, max_slots=2, prefix_cache=True,
+                             prefill_chunk_tokens=16, decode_horizon=4)
+                    for c in (0, 0, 1, 2)),
+        rtt_s=DEFAULT_RTT, model_names=("qwen3-8b",)), zoo=zoo, host=host)
+    jobs, sid = [], 0
+    for j in range(6):
+        obs = StageObservation(app=0, role=0, position=0.0, invocation_idx=0,
+                               tools_available=0, cot=False, prompt_len=40,
+                               model_id=0, text="route test",
+                               src_cluster=j % 3)
+        prompt = [int(t) for t in (np.arange(24) * (j + 3)) % 97 + 1]
+        stages = []
+        for k in range(3):
+            prompt = prompt + [sid % 89 + 1] * 8
+            stages.append(LiveStage(stage_id=sid, job_id=j,
+                                    deps=[sid - 1] if k else [], obs=obs,
+                                    interactive=True, tokens=list(prompt),
+                                    max_new=4))
+            sid += 1
+        jobs.append(LiveJob(job_id=j, app="t", interactive=True,
+                            arrival_s=0.0, stages=stages))
+    gw = ClusterGateway(nodes, DEFAULT_RTT, predictor=StubPred(4.0),
+                        policy="maestro")
+    telemetry.enable_spans(log)
+    gw.submit_jobs(jobs)
+    for _ in range(600):
+        gw.step()
+        if len(gw.done) == sid:
+            break
+    telemetry.enable_spans(None)
+    assert len(gw.done) == sid
+    assert all(ev.preemptions == 0 for ev in gw.telemetry.events.values())
+    return gw, nodes, [s for job in jobs for s in job.stages]
+
+
+def test_child_counters_match_the_gateways_placements(spans_reset):
+    gw, nodes, stages = _four_node_run()
+    stats = [n.kv_stats() for n in nodes]
+    children = [s for s in stages if s.deps]
+    # every child stage was placed once, on the node its counter names
+    assert sum(st["node_child_stages"] for st in stats) == len(children)
+    for n, st in zip(nodes, stats):
+        mine = [s for s in children if gw.placed_on[s.stage_id] == n.node_id]
+        assert st["node_child_stages"] == len(mine)
+        assert st["node_child_affine"] == sum(
+            gw.placed_on[s.deps[0]] == n.node_id for s in mine)
+    affine = sum(st["node_child_affine"] for st in stats)
+    assert 0 < affine < len(children)      # both kinds occur in this run
+
+
+def test_route_span_carries_its_placement(spans_reset):
+    from repro.core.topology import DEFAULT_RTT
+    assert telemetry.span("gateway.route", req=0, src=0, node=0, rtt_ms=0.5,
+                          affine=False) is telemetry.NULL_SPAN
+    log = _SpanLog()
+    gw, nodes, stages = _four_node_run(log)
+    routes = [s for s in log.spans if s["name"] == "maestro:gateway.route"]
+    assert len(routes) == len(stages)          # one per placement
+    by_id = {s.stage_id: s for s in stages}
+    for r in routes:
+        assert r["parent"]["name"] == "maestro:gateway.dispatch"
+        a = r["args"]
+        assert set(a) == {"req", "src", "node", "rtt_ms", "affine"}
+        stage = by_id[a["req"]]
+        assert a["node"] == gw.placed_on[stage.stage_id]
+        assert a["src"] == stage.obs.src_cluster
+        assert a["rtt_ms"] == pytest.approx(
+            1e3 * DEFAULT_RTT[a["src"], nodes[a["node"]].cluster_id])
+        assert a["affine"] == (bool(stage.deps)
+                               and gw.placed_on[stage.deps[0]] == a["node"])
