@@ -73,13 +73,13 @@ def _model_jit(model: "Model", key: tuple, builder):
     10-model fleet recompiled identical programs on every activation;
     keying the wrapper on the model (plus everything the traced program
     closes over: kernel backend, page size) makes compilation once-per-
-    program for the model's whole lifetime."""
-    cache = getattr(model, "_engine_jit_cache", None)
-    if cache is None:
-        cache = model._engine_jit_cache = {}
+    program for the model's whole lifetime. Nodes stepping on threads of
+    their own share the model: ``setdefault`` hands every thread the
+    wrapper stored first, so none compiles a program twice."""
+    cache = vars(model).setdefault("_engine_jit_cache", {})
     fn = cache.get(key)
     if fn is None:
-        fn = cache[key] = builder()
+        fn = cache.setdefault(key, builder())
     return fn
 
 

@@ -36,14 +36,19 @@ Two clocks plug in:
 - ``clock="wall"``: real monotonic time. Process-backend workers FREE-RUN
   (continuous stepping in their own processes); the gateway submits and
   polls asynchronously, so engine iterations genuinely overlap across
-  processes in measured time. Queue delay and SLO attainment come out in
-  real elapsed seconds; the policies' cost estimates (``t_exec_est``,
-  deadline profiling) remain the nominal virtual model, so scheduling
-  decisions share one code path on both clocks.
+  processes in measured time. In-process nodes are stepped by the gateway:
+  when two or more are busy, each steps on its own thread and the gateway
+  joins them, so one node's chip computes while another's step waits on
+  its host pull; their outputs drain in fleet order after the join. Queue
+  delay and SLO attainment come out in real elapsed seconds; the policies'
+  cost estimates (``t_exec_est``, deadline profiling) remain the nominal
+  virtual model, so scheduling decisions share one code path on both
+  clocks.
 """
 from __future__ import annotations
 
 import collections
+import concurrent.futures
 import dataclasses
 import heapq
 import time
@@ -70,6 +75,13 @@ from repro.serving.telemetry import (GatewayMetrics, NodeDeathEvent,
 from repro.serving.worker import WorkerDied, close_fleet
 
 COLD_START_THRESHOLD_S = 0.01
+
+
+def _timed_step(node: NodeRuntime) -> Tuple[Dict[str, List[Request]], float]:
+    """One node's step and the wall seconds it took, timed where it ran."""
+    t0 = time.perf_counter()
+    out = node.step()
+    return out, time.perf_counter() - t0
 
 
 @dataclasses.dataclass
@@ -210,6 +222,9 @@ class ClusterGateway:
         # wall-clock accounting: real busy seconds per node (in-process
         # backend; worker processes report their own step wall)
         self._node_busy_s: Dict[int, float] = {nid: 0.0 for nid in self.fleet}
+        # wall clock + in-process: the threads that step busy nodes at
+        # once, made on the first pass with two or more of them
+        self._step_pool: Optional[concurrent.futures.ThreadPoolExecutor] = None
         self._run_wall0: Optional[float] = None
         if self.clock.name == "wall" and self.node_backend != "inproc":
             # workers free-run: continuous stepping inside each child, the
@@ -651,8 +666,11 @@ class ClusterGateway:
         return m
 
     def close(self) -> None:
-        """Shut worker processes down (no-op for the in-process backend),
-        including handles already dead or retired mid-run."""
+        """Shut worker processes down, including handles already dead or
+        retired mid-run, and the in-process node-stepping threads."""
+        if self._step_pool is not None:
+            self._step_pool.shutdown()
+            self._step_pool = None
         close_fleet(list(self.fleet.values()) + self._gone_handles)
 
     def warmup(self) -> None:
@@ -786,18 +804,29 @@ class ClusterGateway:
                     self._on_node_death(e.node_id, self.clock.now(),
                                         cause=str(e))
             return False      # polling is not compute: let advance() pace
-        # wall + in-process: the gateway itself steps busy engines, one
-        # node after another — real elapsed time, but serialized in this
-        # process (the measured contrast to the free-running worker fleet)
-        stepped = False
-        for nid, node in self.fleet.items():
-            if node.has_work():
-                t0 = time.perf_counter()
-                out = node.step()
-                self._node_busy_s[nid] += time.perf_counter() - t0
-                stepped = True
-                self._drain(out, self.clock.now())
-        return stepped
+        # wall + in-process: the gateway steps the busy nodes itself. Two or
+        # more step at once, one thread each, so every chip computes while
+        # another node's step waits on its host pull (JAX releases the GIL
+        # there); one steps on this thread. No node is touched here until
+        # all have returned; outputs then drain in fleet order, and a step's
+        # exception re-raises in that order.
+        busy = [(nid, node) for nid, node in self.fleet.items()
+                if node.has_work()]
+        if len(busy) > 1:
+            if self._step_pool is None:
+                self._step_pool = concurrent.futures.ThreadPoolExecutor(
+                    max_workers=len(self.fleet),
+                    thread_name_prefix="node-step")
+            futures = [self._step_pool.submit(_timed_step, node)
+                       for _, node in busy]
+            concurrent.futures.wait(futures)
+            results = (f.result() for f in futures)
+        else:
+            results = (_timed_step(node) for _, node in busy)
+        for (nid, _), (out, busy_s) in zip(busy, results):
+            self._node_busy_s[nid] += busy_s
+            self._drain(out, self.clock.now())
+        return bool(busy)
 
     def _drain(self, out: Dict[str, List[Request]], now: float) -> None:
         for model, reqs in out.items():

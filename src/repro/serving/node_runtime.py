@@ -74,6 +74,8 @@ class NodeRuntime:
         # those of them whose parent ran on this node
         self.stat_child_stages = 0
         self.stat_child_affine = 0
+        # wall seconds spent inside step()
+        self.stat_step_s = 0.0
         hw = hardware_spec(self.device)
         profiles = {
             name: ModelProfile(
@@ -265,6 +267,7 @@ class NodeRuntime:
         return any(e.waiting or e.active for e in self.engines.values())
 
     def step(self) -> Dict[str, list]:
+        t0 = time.perf_counter()
         out = {}
         with span("node.step", node=self.node_id):
             for name, eng in self.engines.items():
@@ -279,6 +282,7 @@ class NodeRuntime:
                 if eng.finished:
                     out[name] = eng.finished[:]
                     eng.finished.clear()
+        self.stat_step_s += time.perf_counter() - t0
         return out
 
     # -------------------------------------------------------------- signals
@@ -340,7 +344,10 @@ class NodeRuntime:
                # routing: child stages submitted here, and those placed on
                # the node their parent ran on
                "node_child_stages": self.stat_child_stages,
-               "node_child_affine": self.stat_child_affine}
+               "node_child_affine": self.stat_child_affine,
+               # wall seconds inside step(): summed over nodes and divided
+               # by a window, how many nodes stepped at once on average
+               "node_step_s": self.stat_step_s}
         if self.arena.prefix_index is not None:
             out.update(self.arena.prefix_index.stats())
         return out

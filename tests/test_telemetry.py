@@ -8,6 +8,7 @@ on a single-sample run would poison every downstream comparison, so the
 extreme-tail columns (p99/p99.9) are pinned to 0.0 below two samples."""
 import collections
 import math
+import time
 import types
 
 import jax
@@ -371,6 +372,31 @@ def test_growth_counters_in_node_kv_stats(tiny_model):
     assert st["engine_chunk_lanes"] == 2
     assert (st["engine_pin_trims"], st["engine_grow_waits"],
             st["engine_preemptions"]) == (3, 2, 1)
+
+
+def test_step_seconds_in_node_kv_stats(tiny_model):
+    """``node_step_s`` is the wall time spent inside ``NodeRuntime.step``:
+    zero before the first step, then growing with each step, and never
+    more than the time around the steps."""
+    from repro.serving.node_runtime import NodeRuntime
+    cfg, m, params = tiny_model
+    host = jax.tree.map(np.asarray, params)
+    node = NodeRuntime(0, 0, {cfg.name: m}, {cfg.name: host},
+                       hbm_budget=1.2e9, max_slots=3, s_max=64,
+                       prefill_chunk_tokens=16)
+    assert node.kv_stats()["node_step_s"] == 0.0
+    node.submit(cfg.name, Request(req_id=0, tokens=_tokens(20, 7),
+                                  max_new=4))
+    seen, outside = [], 0.0
+    for _ in range(30):
+        t0 = time.perf_counter()
+        node.step()
+        outside += time.perf_counter() - t0
+        seen.append(node.kv_stats()["node_step_s"])
+        if not node.has_work():
+            break
+    assert all(b > a for a, b in zip([0.0] + seen, seen))
+    assert 0 < seen[-1] <= outside
 
 
 # ------------------------------------------------------- routing counters
